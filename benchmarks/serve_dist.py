@@ -40,7 +40,8 @@ import subprocess
 import sys
 import textwrap
 
-from benchmarks.serve_throughput import DIST_JSON_PATH, run_mesh_sweep
+from benchmarks.serve_throughput import (DIST_JSON_PATH, refuse_on_tpu,
+                                         run_mesh_sweep)
 
 _SKEW_CHILD = textwrap.dedent("""
     import os, sys
@@ -51,6 +52,7 @@ _SKEW_CHILD = textwrap.dedent("""
     import json, time
     import jax, jax.numpy as jnp, numpy as np
     from repro.core import moe as moe_lib
+    from repro.dist import make_mesh
     from repro.serve.expert_cache import PagedMoE
     from repro.serve.placement import ElasticPolicy
     from repro.serve.transfer import TransferEngine
@@ -85,7 +87,7 @@ _SKEW_CHILD = textwrap.dedent("""
     refs = [np.asarray(moe_lib.apply_moe(params, cfg, x, task_id=0)[0])
             for x in xs]
 
-    mesh = jax.make_mesh((1, n), ("data", "model"))
+    mesh = make_mesh((1, n), ("data", "model"))
     engine = TransferEngine(workers=2) if mode == "elastic_async" else None
     placement = "static" if mode == "static" else ElasticPolicy(
         rebalance_every=2, replicate_factor=2.0)
@@ -184,6 +186,7 @@ def _child(repo: str, mesh: int, iters: int, zipf_a: float,
 def run_skew_sweep(quick: bool = False, skew: str = "zipf:1.2"):
     """Skewed static-vs-elastic placement sweep; merges a ``skew``
     section (with its acceptance flags) into ``bench/serve_dist.json``."""
+    refuse_on_tpu("serve_dist skew sweep")
     zipf_a = _parse_skew(skew)
     meshes = (4,) if quick else (2, 4)
     iters = 3 if quick else 6

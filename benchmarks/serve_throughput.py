@@ -375,6 +375,7 @@ _MESH_CHILD = textwrap.dedent("""
     import json, time
     import jax, numpy as np
     from repro import configs
+    from repro.dist import make_mesh
     from repro.dist.sharding import ShardingRules
     from repro.models import vit as V
     from repro.serve.vision import M3ViTServer
@@ -390,7 +391,7 @@ _MESH_CHILD = textwrap.dedent("""
     # additionally run concurrently)
     cfg = replace(cfg, moe=replace(cfg.moe, num_experts=64, d_ff=1024))
     params = V.init_params(jax.random.PRNGKey(0), cfg)
-    mesh = jax.make_mesh((1, n), ("data", "model")) if n > 1 else None
+    mesh = make_mesh((1, n), ("data", "model")) if n > 1 else None
     # hybrid placement (the M3ViT/UbiMoE co-design split): the tiny dense
     # trunk replicates, ONLY the expert banks partition — every mesh size
     # pays an identical trunk cost and the measured delta is pure expert
@@ -460,6 +461,16 @@ _MESH_CHILD = textwrap.dedent("""
 """)
 
 
+def refuse_on_tpu(name: str) -> None:
+    """The mesh sweeps time forced host CPU devices in child processes.
+    With a TPU attached they would print CPU numbers as if they were the
+    chip's (and a child could not reach the chip this process holds), so
+    they refuse to run there."""
+    if jax.default_backend() == "tpu":
+        raise SystemExit(f"{name}: this sweep times forced host CPU devices "
+                         "and does not run with a TPU attached")
+
+
 def run_mesh_sweep(quick: bool = False):
     """Distributed-serving benchmark (registered as ``serve_dist``).
 
@@ -468,6 +479,7 @@ def run_mesh_sweep(quick: bool = False):
     Writes ``serve_dist.json`` (override via ``BENCH_DIST_JSON``) with the
     acceptance flags; raises if the scaling contract breaks.
     """
+    refuse_on_tpu("serve_dist mesh sweep")
     sizes = (1, 4) if quick else (1, 2, 4, 8)
     iters = 4 if quick else 10
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

@@ -4,34 +4,10 @@ The paper's five techniques as composable modules (``repro.core``), a
 10-architecture model zoo (``repro.configs``/``repro.models``), Pallas TPU
 kernels (``repro.kernels``), and the distributed substrate (data, optim,
 checkpoint, train, serve, dist, launch, roofline).
+
+Importing the package sets no platform: a process that forces host
+devices (``--xla_force_host_platform_device_count``) sets
+``JAX_PLATFORMS=cpu`` itself.
 """
 
 __version__ = "1.0.0"
-
-import os as _os
-
-# --xla_force_host_platform_device_count only has an effect on the host
-# (CPU) backend, so a process that sets it (the 512-device dry-run, the
-# multi-device subprocess tests) wants CPU devices.  Default JAX_PLATFORMS
-# accordingly before jax initializes its backends — otherwise an installed
-# libtpu probes the cloud TPU metadata server first, which hangs for
-# minutes in hermetic environments.
-if "--xla_force_host_platform_device_count" in _os.environ.get("XLA_FLAGS", ""):
-    _os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax as _jax
-
-    # the env var is snapshotted into jax.config at `import jax`, which may
-    # have happened before this package was imported — update the live
-    # config too (still before any backend is instantiated)
-    if not getattr(_jax.config, "jax_platforms", None):
-        _jax.config.update("jax_platforms", "cpu")
-    del _jax
-del _os
-
-# Importing the dist package installs the jax.shard_map compatibility
-# wrapper (see dist/_compat.py) — core/moe.py's expert-parallel path calls
-# jax.shard_map directly, and on older jax releases only the
-# jax.experimental spelling exists.
-from repro import dist as _dist  # noqa: F401  (imported for its side effect)
-
-del _dist

@@ -133,6 +133,86 @@ def lut_correction(y, table, step_log2: int):
     return jnp.where(finite, out, y * 0.5 * (1.0 + jnp.sign(y)))
 
 
+LANES = 128
+
+# Eigen's / XLA's f32 rational erf fit on [-4, 4] (odd numerator over even
+# denominator in x²); outside that range erf(x) rounds to ±1 in f32.
+_ERF_ALPHA = (-2.72614225801306e-10, 2.77068142495902e-08,
+              -2.10102402082508e-06, -5.69250639462346e-05,
+              -7.34990630326855e-04, -2.95459980854025e-03,
+              -1.60960333262415e-02)
+_ERF_BETA = (-1.45660718464996e-05, -2.13374055278905e-04,
+             -1.68282697438203e-03, -7.37332916720468e-03,
+             -1.42647390514189e-02)
+
+
+def erf_rational(x):
+    """f32 erf from clamp, multiply, add and divide only.
+
+    Pallas TPU has no lowering for ``lax.erf``; kernel epilogues use this
+    instead.  Max abs error against float64 erf is below 1e-6 (tested).
+    """
+    x = jnp.clip(x, -4.0, 4.0)
+    x2 = x * x
+    num = jnp.full_like(x2, _ERF_ALPHA[0])
+    for c in _ERF_ALPHA[1:]:
+        num = num * x2 + c
+    den = jnp.full_like(x2, _ERF_BETA[0])
+    for c in _ERF_BETA[1:]:
+        den = den * x2 + c
+    return x * num / den
+
+
+def kernel_gelu(x):
+    """Exact-form GELU for kernel bodies (``erf_rational`` in place of
+    ``lax.erf``)."""
+    return x * 0.5 * (1.0 + erf_rational(x * np.float32(1.0 / math.sqrt(2.0))))
+
+
+def lut_table_lanes(table) -> np.ndarray:
+    """The n-entry δ table as zero-padded (ceil(n/128), 128) f32 rows — the
+    layout ``lut_correction_lanes`` reads (a VMEM-friendly 2-D block)."""
+    t = np.asarray(table, np.float32).reshape(-1)
+    rows = -(-t.shape[0] // LANES)
+    out = np.zeros((rows * LANES,), np.float32)
+    out[:t.shape[0]] = t
+    return out.reshape(rows, LANES)
+
+
+def lut_correction_lanes(y, table_rows, step_log2: int, n: int):
+    """``lut_correction`` for Pallas kernel bodies, whose lowering has no
+    general gather.
+
+    ``y`` is (R, C) f32 with C a multiple of 128; ``table_rows`` is the
+    ``lut_table_lanes`` layout of the n-entry table.  Entry ``idx`` sits at
+    row ``idx >> 7``, lane ``idx & 127``: each table row is read with a
+    within-lane-tile gather and the row is picked by compare-select, so
+    the result equals ``lut_correction`` entry for entry.
+    """
+    scale = 2.0 ** (-step_log2)
+    ay = jnp.abs(y)
+    finite = jnp.isfinite(y)
+    in_range = finite & (ay * scale < n)
+    idx = jnp.clip(jnp.round(ay * scale).astype(jnp.int32), 0, n - 1)
+    hi = jax.lax.shift_right_logical(idx, 7)
+    lo = idx & (LANES - 1)
+    chunks = []
+    for j in range(y.shape[1] // LANES):
+        lo_j = lo[:, j * LANES:(j + 1) * LANES]
+        hi_j = hi[:, j * LANES:(j + 1) * LANES]
+        delta = jnp.zeros(lo_j.shape, jnp.float32)
+        for r in range(table_rows.shape[0]):
+            row = jnp.broadcast_to(table_rows[r:r + 1, :], lo_j.shape)
+            got = jnp.take_along_axis(row, lo_j, axis=1,
+                                      mode="promise_in_bounds")
+            delta = jnp.where(hi_j == r, got, delta)
+        chunks.append(delta)
+    delta = chunks[0] if len(chunks) == 1 else jnp.concatenate(chunks, 1)
+    delta = jnp.where(in_range, delta, 0.0)
+    out = jnp.maximum(y, 0.0) - delta
+    return jnp.where(finite, out, y * 0.5 * (1.0 + jnp.sign(y)))
+
+
 def lut_activation(
     x: jax.Array,
     kind: str = "gelu",

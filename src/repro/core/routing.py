@@ -36,6 +36,7 @@ bit-identical to the gather path.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -48,6 +49,7 @@ __all__ = [
     "route_topk",
     "build_dispatch",
     "dispatch",
+    "combine_rows",
     "dispatch_counts",
     "dispatch_onehot",
     "combine",
@@ -147,14 +149,26 @@ def combine(expert_out: jax.Array, routing: Routing) -> jax.Array:
     k experts — the paper's "weighted accumulation atop the existing output
     buffer" done by the indirect writer.
     """
-    t, k = routing.expert.shape
     e = routing.expert.reshape(-1)
     p = routing.position.reshape(-1)
-    v = routing.valid.reshape(-1)
-    g = routing.gate.reshape(-1)
     rows = expert_out[e, jnp.minimum(p, expert_out.shape[1] - 1)]
-    rows = rows * (g * v).astype(rows.dtype)[:, None]
-    return rows.reshape(t, k, -1).sum(axis=1)
+    return combine_rows(rows, routing)
+
+
+def combine_rows(rows: jax.Array, routing: Routing) -> jax.Array:
+    """Gate-weighted sum of each token's k expert rows: (T*k, d) -> (T, d).
+
+    The shared tail of ``combine`` and the paged serving layer's combine.
+    The k-sum is one contraction (a dot), not a multiply then a reduce:
+    XLA may fuse the latter into fused multiply-adds inside a jitted
+    caller but not in an eager one, which made the two paths differ in
+    the last bit.  ``HIGHEST`` keeps f32 operands f32 on the TPU, whose
+    default dot precision rounds them to bf16.
+    """
+    t, k = routing.expert.shape
+    w = (routing.gate * routing.valid).astype(rows.dtype)
+    return jnp.einsum("tk,tkd->td", w, rows.reshape(t, k, -1),
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def dispatch_onehot(x: jax.Array, routing: Routing, num_experts: int,
@@ -200,5 +214,9 @@ def load_balance_loss(probs: jax.Array, expert: jax.Array, num_experts: int,
         expert.reshape(-1)].add(jnp.repeat(w, k))
     denom = jnp.maximum(w.sum(), 1.0)
     f = counts / (denom * k)
-    p = (probs * w[:, None]).sum(axis=0) / denom
-    return num_experts * jnp.sum(f * p)
+    # sums of products as f32 dots, as in ``combine_rows``: no multiply
+    # then reduce for XLA to contract into FMAs in one caller and not another
+    dot = functools.partial(jnp.dot, precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
+    p = dot(w, probs) / denom
+    return num_experts * dot(f, p)

@@ -1,16 +1,13 @@
-"""Distribution layer: sharding rules + compressed collectives.
+"""Distribution layer: meshes, sharding rules, compressed collectives.
 
-``dist.sharding`` holds the one layout table every (arch × mesh) cell
-shares — logical activation constraints (``constrain``/``use_rules``),
-regex parameter patterns (``param_sharding_rules``), and the derived
-batch/optimizer-state tables.  ``dist.compress`` holds the int8
-error-feedback gradient collectives used for the cross-pod all-reduce.
-
-Importing this package also installs the ``jax.shard_map`` compatibility
-wrapper (see ``_compat``) so every caller can use the modern API
-spelling regardless of the installed jax version.
+``dist.sharding`` holds the mesh constructor (``make_mesh``, Auto axes)
+and the one layout table every (arch × mesh) cell shares — logical
+activation constraints (``constrain``/``use_rules``), regex parameter
+patterns (``param_sharding_rules``), and the derived batch/optimizer-state
+tables.  ``dist.compress`` holds the int8 error-feedback gradient
+collectives used for the cross-pod all-reduce.
 """
 
-from repro.dist import _compat as _compat
+from repro.dist.sharding import make_mesh
 
-_compat.install_shard_map()
+__all__ = ["make_mesh"]

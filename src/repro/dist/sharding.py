@@ -42,10 +42,24 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 __all__ = [
-    "ShardingRules", "use_rules", "current_rules", "constrain",
+    "make_mesh", "ShardingRules", "use_rules", "current_rules", "constrain",
     "param_sharding_rules", "batch_sharding", "opt_state_shardings",
     "ep_dispatch_sharding", "_trim_spec",
 ]
+
+
+def make_mesh(shape, axes, *, devices=None) -> jax.sharding.Mesh:
+    """The repo's one mesh constructor: every axis ``AxisType.Auto``.
+
+    ``jax.make_mesh`` defaults to Explicit axes, under which the rules'
+    ``with_sharding_constraint`` layouts and the serving gathers/scatters
+    raise ``ShardingTypeError``; this code relies on GSPMD propagation.
+    ``devices`` defaults to the devices the process sees.
+    """
+    shape, axes = tuple(shape), tuple(axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def ep_dispatch_sharding(mesh, axis: str = "model") -> NamedSharding:

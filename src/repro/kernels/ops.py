@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.gelu import _cached_table
+from repro.core.gelu import _cached_table, lut_table_lanes
 from repro.kernels import decode_fused as _df
 from repro.kernels import flash_attention as _fa
 from repro.kernels import gelu_lut as _gl
@@ -45,6 +45,15 @@ def _pad_to(x, mult: int, axis: int, value=0):
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths, constant_values=value)
+
+
+def _lut_rows(kind: str, step_log2: int, lut_range: float):
+    """(table rows, entry count) of the δ table in the kernels' layout."""
+    table = _cached_table(kind, step_log2, lut_range)
+    return jnp.asarray(lut_table_lanes(table)), table.shape[0]
+
+
+_NO_TABLE = (np.zeros((8, 128), np.float32), 1)
 
 
 def _blocks(op: str, dims: dict, given: dict, impl: str = "pallas") -> dict:
@@ -120,12 +129,11 @@ def unified_linear(x, w, b=None, *, activation=None, use_lut=False,
     xp = _pad_to(_pad_to(x2, bm, 0), bk, 1)
     wp = _pad_to(_pad_to(w, bk, 0), bn, 1)
     bp = None if b is None else _pad_to(b.astype(jnp.float32), bn, 0)
-    table = jnp.asarray(
-        _cached_table(activation or "gelu", step_log2, lut_range)) \
-        if activation in ("gelu", "silu") else jnp.zeros((8,), jnp.float32)
+    table, lut_n = _lut_rows(activation, step_log2, lut_range) \
+        if use_lut and activation in ("gelu", "silu") else _NO_TABLE
     y = _ul.unified_linear_call(
-        xp, wp, bp, table, activation=activation, use_lut=use_lut,
-        step_log2=step_log2,
+        xp, wp, bp, table, lut_n=lut_n, activation=activation,
+        use_lut=use_lut, step_log2=step_log2,
         block_m=bm, block_n=bn, block_k=bk, interpret=interpret)
     return y[:m, :n].reshape(*lead, n)
 
@@ -166,7 +174,7 @@ def moe_gemm(buf, w, group_sizes, *, block_c=None, block_f=None, block_k=None,
 def lut_activation(x, kind="gelu", *, step_log2=-8, lut_range=8.0,
                    block_rows=None, interpret=None):
     """Standalone LUT activation kernel (technique ③).  Elementwise."""
-    table = jnp.asarray(_cached_table(kind, step_log2, lut_range))
+    table, lut_n = _lut_rows(kind, step_log2, lut_range)
     flat = x.reshape(-1)
     n = flat.shape[0]
     lanes = 128
@@ -178,7 +186,8 @@ def lut_activation(x, kind="gelu", *, step_log2=-8, lut_range=8.0,
     rows_p = -(-rows // br) * br
     xp = jnp.zeros((rows_p * lanes,), x.dtype).at[:n].set(flat)
     y = _gl.lut_activation_call(xp.reshape(rows_p, lanes), table,
-                                step_log2=step_log2, block_rows=br,
+                                lut_n=lut_n, step_log2=step_log2,
+                                block_rows=br,
                                 interpret=interpret)
     return y.reshape(-1)[:n].reshape(x.shape)
 
@@ -228,24 +237,25 @@ def fused_moe_ffn(x, params, expert, gate, position, valid, group_sizes, *,
         .at[eidx, p_safe].set(tokids)[:, :c]
     gates = jnp.zeros((e_num, c + 1), jnp.float32) \
         .at[eidx, p_safe].set(gv)[:, :c]
-    tok_idx = _pad_to(tok_idx, bc, 1, value=-1)
-    gates = _pad_to(gates, bc, 1)
+    # (E, Cp, 1) columns: Mosaic blocks of (1, block_c, 1)
+    tok_idx = _pad_to(tok_idx, bc, 1, value=-1)[..., None]
+    gates = _pad_to(gates, bc, 1)[..., None]
 
     xp = _pad_to(_pad_to(x, 128, 0), 128, 1)
     wp = []
     for w in weights:
-        w = _pad_to(w, 128, 1)                   # d or f axis
-        if w.ndim == 3:
-            w = _pad_to(w, 128, 2)
-        wp.append(w)
-    table = jnp.asarray(
-        _cached_table("silu" if kind == "swiglu" else "gelu",
-                      step_log2, lut_range))[None, :] if use_lut \
-        else jnp.zeros((1, 8), jnp.float32)
+        if w.ndim == 2:                          # bias (E, n) -> (E, 1, n)
+            w = w[:, None, :]
+        else:
+            w = _pad_to(w, 128, 1)               # d or f axis
+        wp.append(_pad_to(w, 128, 2))
+    table, lut_n = _lut_rows("silu" if kind == "swiglu" else "gelu",
+                             step_log2, lut_range) if use_lut else _NO_TABLE
     out = _mf.fused_moe_call(
         tok_idx, gates, xp, tuple(wp), table,
         group_sizes.astype(jnp.int32), kind=kind, block_c=bc,
-        use_lut=use_lut, step_log2=step_log2, interpret=interpret)
+        use_lut=use_lut, step_log2=step_log2, lut_n=lut_n,
+        interpret=interpret)
     return out[:t, :d].astype(x.dtype)
 
 

@@ -9,9 +9,11 @@ The decision now lives here, in one place, with three explicit states:
                 (the only mode CPU CI can run).
   * ``True``  — force the interpreter even on TPU (debugging a kernel body
                 with real shapes).
-  * ``False`` — require compiled kernels.  Off-TPU this cannot be honored;
-                the ops-layer capability predicates reject the kernel impls
-                with a recorded reason instead of silently interpreting.
+  * ``False`` — require compiled kernels, on any backend.  Off-TPU the
+                ops-layer capability predicates reject the kernel impls
+                with a recorded reason before a kernel is called; a direct
+                call lowers to Mosaic (what a compile for a described TPU
+                topology needs).
 
 ``repro.ops`` threads the ambient :class:`~repro.ops.ComputePolicy`'s
 ``interpret`` field through the kernel wrappers, and ``dispatch_report()``
@@ -36,15 +38,12 @@ def default_interpret() -> bool:
 def resolve_interpret(explicit: Optional[bool] = None) -> bool:
     """Resolve the three-state ``interpret`` decision to a concrete bool.
 
-    ``False`` (require compiled) off-TPU resolves to ``True`` as a last
-    resort — callers that must *reject* rather than degrade (the registry
-    impl predicates) check ``default_interpret()`` themselves before the
-    kernel is ever invoked.
+    Only ``None`` consults the backend; an explicit ``False`` stays
+    ``False`` everywhere (the registry impl predicates reject compiled-only
+    policies off-TPU before the kernel is ever invoked).
     """
     if explicit is None:
         return default_interpret()
-    if explicit is False and default_interpret():
-        return True
     return bool(explicit)
 
 

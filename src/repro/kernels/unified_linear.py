@@ -14,7 +14,9 @@ The paper's manually flattened variable-bound loop maps to the Pallas grid:
 M, N, K are call-time values, the kernel is shape-polymorphic by re-lowering.
 
 The LUT-activation epilogue (§IV-C fused into §IV-E) takes the δ table as an
-extra whole-block input, so the fused op realizes techniques ③+④ together.
+extra whole-block input (``core.gelu.lut_table_lanes`` rows), so the fused op
+realizes techniques ③+④ together.  The exact GELU epilogue uses
+``core.gelu.kernel_gelu``: Mosaic has no ``erf`` lowering.
 """
 
 from __future__ import annotations
@@ -26,28 +28,29 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.gelu import lut_correction
+from repro.core.gelu import kernel_gelu, lut_correction_lanes
 from repro.kernels.runtime import resolve_interpret
 
 __all__ = ["unified_linear_kernel", "unified_linear_call"]
 
 
-def _epilogue(y, activation: str | None, use_lut: bool, table, step_log2: int):
+def _epilogue(y, activation: str | None, use_lut: bool, table, step_log2: int,
+              lut_n: int):
     if activation in (None, "none"):
         return y
     if activation == "relu":
         return jnp.maximum(y, 0.0)
     if use_lut:
-        return lut_correction(y, table, step_log2)
+        return lut_correction_lanes(y, table, step_log2, lut_n)
     if activation == "gelu":
-        return y * 0.5 * (1.0 + jax.lax.erf(y / jnp.sqrt(2.0).astype(y.dtype)))
+        return kernel_gelu(y)
     if activation == "silu":
         return y * jax.nn.sigmoid(y)
     raise ValueError(activation)
 
 
 def unified_linear_kernel(x_ref, w_ref, b_ref, t_ref, o_ref, acc_scr, *,
-                          activation, use_lut, step_log2, has_bias):
+                          activation, use_lut, step_log2, lut_n, has_bias):
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -64,12 +67,13 @@ def unified_linear_kernel(x_ref, w_ref, b_ref, t_ref, o_ref, acc_scr, *,
         y = acc_scr[...]
         if has_bias:
             y = y + b_ref[0].astype(jnp.float32)      # widened f32 bias
-        y = _epilogue(y, activation, use_lut, t_ref[0], step_log2)
+        y = _epilogue(y, activation, use_lut, t_ref[...], step_log2, lut_n)
         o_ref[...] = y.astype(o_ref.dtype)
 
 
 def unified_linear_call(
-    x, w, b, table, *,
+    x, w, b, table_rows, *,
+    lut_n: int,
     activation: str | None = None,
     use_lut: bool = False,
     step_log2: int = -8,
@@ -80,7 +84,9 @@ def unified_linear_call(
 ):
     """Raw call on padded operands.  Use ``ops.unified_linear`` instead.
 
-    x: (M, K), w: (K, N), b: (N,) f32 or None, table: (n,) f32.
+    x: (M, K), w: (K, N), b: (N,) f32 or None; table_rows: the
+    ``lut_table_lanes`` layout of the lut_n-entry δ table (read only by a LUT
+    epilogue).
     M % block_m == N % block_n == K % block_k == 0 (wrapper pads; zero pads
     contribute 0 to the accumulator so no masking is needed).
     """
@@ -92,10 +98,9 @@ def unified_linear_call(
     if b is None:
         b = jnp.zeros((n,), jnp.float32)
     b2 = b[None, :]
-    t2 = table[None, :]
     kernel = functools.partial(
         unified_linear_kernel, activation=activation, use_lut=use_lut,
-        step_log2=step_log2, has_bias=has_bias)
+        step_log2=step_log2, lut_n=lut_n, has_bias=has_bias)
     return pl.pallas_call(
         kernel,
         grid=(nm, nn, nk),
@@ -103,11 +108,11 @@ def unified_linear_call(
             pl.BlockSpec((block_m, block_k), lambda mi, ni, ki: (mi, ki)),
             pl.BlockSpec((block_k, block_n), lambda mi, ni, ki: (ki, ni)),
             pl.BlockSpec((1, block_n), lambda mi, ni, ki: (0, ni)),
-            pl.BlockSpec((1, table.shape[0]), lambda mi, ni, ki: (0, 0)),
+            pl.BlockSpec(table_rows.shape, lambda mi, ni, ki: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n),
                                lambda mi, ni, ki: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
-    )(x, w, b2, t2)
+    )(x, w, b2, table_rows)

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: prove the distribution config is coherent.
 
@@ -16,9 +17,10 @@ meshes (single-pod 16×16 and multi-pod 2×16×16), this script:
      ``cost_analysis()`` (FLOPs/bytes → §Roofline), and the parsed
      per-collective byte counts from the optimized HLO.
 
-The two lines above MUST run before any jax import: jax locks the device
+The lines above MUST run before any jax import: jax locks the device
 count at first init, and the production meshes need 512 host placeholder
-devices.  This flag is set ONLY here — tests/benches see 1 device.
+devices (forced host devices exist only on the CPU platform).  This
+flag is set ONLY here — tests/benches see 1 device.
 
 Usage:
   python -m repro.launch.dryrun --arch llama3_2_1b --shape train_4k [--multi-pod]

@@ -2,8 +2,9 @@
 
 ``make_production_mesh`` is a FUNCTION (not a module constant) so importing
 this module never touches jax device state — required because the dry-run
-must set ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` *before*
-jax initializes, while smoke tests and benchmarks must see 1 device.
+must set ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` (and
+``JAX_PLATFORMS=cpu``) *before* jax initializes, while smoke tests and
+benchmarks must see 1 device.
 
 Meshes (TPU v5e pods, 256 chips each):
 
@@ -18,7 +19,7 @@ change here, no model or rules change.
 
 from __future__ import annotations
 
-import jax
+from repro.dist import make_mesh
 
 __all__ = ["make_production_mesh", "HW"]
 
@@ -26,7 +27,7 @@ __all__ = ["make_production_mesh", "HW"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 class HW:

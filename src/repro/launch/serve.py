@@ -16,10 +16,11 @@ Usage:
   python -m repro.launch.serve --arch llama3_2_1b --smoke --quant int8 \
       --dispatch-report
   # mesh serving ("DxM" = data x model): batch/KV state sharded over data,
-  # tensor/expert parallelism over model.  Off-TPU the devices are forced
-  # host (CPU) shards, same as dryrun / the dist tests:
-  python -m repro.launch.serve --arch llama3_2_1b --smoke --mesh 2x2
+  # tensor/expert parallelism over model, on the devices the process sees
+  # (four TPU chips for 1x4).  A CPU rehearsal forces host devices itself:
   python -m repro.launch.serve --arch m3vit --smoke --scheduler --mesh 1x4
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+      python -m repro.launch.serve --arch llama3_2_1b --smoke --mesh 2x2
   # factored experts: shared basis (pinned on device) + low-rank or
   # butterfly per-expert deltas (paged) — 10-100x more experts per byte
   # of --expert-budget-bytes; composes with --quant (int8 deltas):
@@ -36,17 +37,17 @@ Usage:
 
 from __future__ import annotations
 
-import os
-import sys
+import argparse
+import time
 
+import jax
+import numpy as np
 
-def _mesh_arg(argv) -> str | None:
-    for i, a in enumerate(argv):
-        if a == "--mesh" and i + 1 < len(argv):
-            return argv[i + 1]
-        if a.startswith("--mesh="):
-            return a.split("=", 1)[1]
-    return None
+from repro import configs
+from repro.dist import make_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models import model as M
+from repro.serve import LMBackend, Request, Scheduler, ServeConfig, ServingEngine
 
 
 def _parse_factor(spec: str) -> tuple[str, int]:
@@ -95,42 +96,6 @@ def _parse_mesh(spec: str) -> tuple[int, int]:
     if d < 1 or m < 1:
         raise SystemExit(f"--mesh axes must be >= 1, got {spec!r}")
     return d, m
-
-
-# --mesh needs its device count BEFORE jax initializes (jax locks the
-# device count at first init) — peek at argv and force host devices, the
-# same pattern launch/dryrun.py and the dist subprocess tests use.
-def _accelerators_likely() -> bool:
-    """Best-effort pre-jax-init accelerator detection: forcing host CPU
-    shards must not silently shadow real devices."""
-    if os.environ.get("JAX_PLATFORMS", "cpu").lower() not in ("", "cpu"):
-        return True
-    if os.environ.get("TPU_NAME") or os.environ.get("COLAB_TPU_ADDR"):
-        return True
-    return bool(os.environ.get("CUDA_VISIBLE_DEVICES", "").strip("- "))
-
-
-_MESH_SPEC = _mesh_arg(sys.argv)
-if _MESH_SPEC and __name__ == "__main__" and not _accelerators_likely():
-    _d, _m = _parse_mesh(_MESH_SPEC)
-    _flags = os.environ.get("XLA_FLAGS", "")
-    if _d * _m > 1 and "xla_force_host_platform_device_count" not in _flags:
-        # append rather than setdefault: a pre-existing unrelated
-        # XLA_FLAGS value must not silently disable device forcing
-        os.environ["XLA_FLAGS"] = (
-            f"{_flags} --xla_force_host_platform_device_count={_d * _m}"
-            .strip())
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-import argparse
-import time
-
-import jax
-import numpy as np
-
-from repro import configs
-from repro.models import model as M
-from repro.serve import LMBackend, Request, Scheduler, ServeConfig, ServingEngine
 
 
 def _serve_scheduler_lm(cfg, params, scfg, args, key, rules=None) -> int:
@@ -196,10 +161,59 @@ def _serve_scheduler_lm(cfg, params, scfg, args, key, rules=None) -> int:
     return 0
 
 
-def _serve_scheduler_vision(cfg, args, rules=None) -> int:
+def mesh_rules(spec: str):
+    """``--mesh DxM`` -> sharding rules over the first D*M devices the
+    process sees; too few devices is an error, never a fallback."""
+    from repro.dist.sharding import ShardingRules
+
+    d, m = _parse_mesh(spec)
+    if d * m > jax.device_count():
+        raise SystemExit(
+            f"--mesh {spec} needs {d * m} devices, the process sees "
+            f"{jax.device_count()} ({jax.devices()[0].platform})")
+    mesh = make_mesh((d, m), ("data", "model"),
+                     devices=jax.devices()[:d * m])
+    # serving keeps dense weights replicated over data (no FSDP): decode
+    # is latency-bound and the weight gathers would dominate
+    return ShardingRules.for_mesh(mesh, fsdp=False)
+
+
+def vision_requests(key, n: int) -> list:
+    """``n`` seeded image requests alternating semseg/depth (4 distinct
+    images, reused round-robin)."""
     from repro.configs import m3vit as MV
-    from repro.models import vit as V
+
+    imgs = np.asarray(jax.random.normal(
+        key, (4, MV.IMAGE_H, MV.IMAGE_W, 3)), np.float32)
+    return [Request(rid=i, task_id=i % len(MV.TASKS),
+                    prompt=imgs[i % imgs.shape[0]])
+            for i in range(n)]
+
+
+def vision_scheduler(cfg, params, *, batch: int,
+                     resident_fraction: float = 0.5, expert_budget_bytes=None,
+                     rules=None, async_paging: bool = False, factor=None,
+                     placement="static") -> Scheduler:
+    """The vision serving stack: ``VisionBackend`` (paged MoE layers) under
+    the task-bucketed ``Scheduler`` with ``batch`` slots in all."""
+    from repro.configs import m3vit as MV
     from repro.serve.vision import VisionBackend
+
+    # factorization happens per MoE layer inside the backend (after the
+    # per-layer slice: the stacked tree's ndim-4 expert leaves are not
+    # factorable, and each layer gets its own basis); quantized expert
+    # leaves re-factor there too — factorize accepts QTensor input
+    backend = VisionBackend(cfg, params,
+                            resident_fraction=resident_fraction,
+                            expert_budget_bytes=expert_budget_bytes,
+                            rules=rules, async_paging=async_paging,
+                            factor=factor, placement=placement)
+    return Scheduler(backend, total_slots=batch, quantum=1,
+                     num_tasks=len(MV.TASKS))
+
+
+def _serve_scheduler_vision(cfg, args, rules=None) -> int:
+    from repro.models import vit as V
 
     key = jax.random.PRNGKey(args.seed)
     k_params, k_data = jax.random.split(key)
@@ -207,26 +221,14 @@ def _serve_scheduler_vision(cfg, args, rules=None) -> int:
     if args.quant:
         from repro.quant import quantize_tree
         params = quantize_tree(params, bits=8 if args.quant == "int8" else 4)
-    # factorization happens per MoE layer inside the backend (after the
-    # per-layer slice: the stacked tree's ndim-4 expert leaves are not
-    # factorable, and each layer gets its own basis); quantized expert
-    # leaves re-factor there too — factorize accepts QTensor input
-    backend = VisionBackend(cfg, params,
-                            resident_fraction=args.resident_fraction,
-                            expert_budget_bytes=args.expert_budget_bytes
-                            or None,
-                            rules=rules, async_paging=args.async_paging,
-                            factor=_factor_spec(args) if args.factor
-                            else None,
-                            placement=args.placement)
-    sched = Scheduler(backend, total_slots=args.batch, quantum=1,
-                      num_tasks=len(MV.TASKS))
-    imgs = np.asarray(jax.random.normal(
-        k_data, (4, MV.IMAGE_H, MV.IMAGE_W, 3)), np.float32)
-    reqs = [Request(rid=i, task_id=i % len(MV.TASKS),
-                    prompt=imgs[i % imgs.shape[0]])
-            for i in range(args.requests)]
-    done = sched.run(reqs)
+    sched = vision_scheduler(
+        cfg, params, batch=args.batch,
+        resident_fraction=args.resident_fraction,
+        expert_budget_bytes=args.expert_budget_bytes or None, rules=rules,
+        async_paging=args.async_paging,
+        factor=_factor_spec(args) if args.factor else None,
+        placement=args.placement)
+    done = sched.run(vision_requests(k_data, args.requests))
     m = sched.metrics()
     cache = m.get("expert_cache", {})
     print(f"[serve] arch={cfg.name} scheduler served {len(done)} "
@@ -296,8 +298,7 @@ def main() -> int:
     ap.add_argument("--mesh", default=None,
                     help="DxM mesh (data x model), e.g. 2x2: serve state "
                          "sharded over data, tensor/expert parallelism "
-                         "over model.  Off-TPU this forces DxM host "
-                         "(CPU) devices before jax init")
+                         "over model, on the first D*M visible devices")
     ap.add_argument("--placement", default="static",
                     choices=["static", "lru", "budget", "elastic"],
                     help="vision scheduler: expert placement policy — "
@@ -331,22 +332,13 @@ def main() -> int:
 
     from repro.ops import dispatch_report, policy_named
 
+    enable_compile_cache()
     rules = None
     if args.mesh:
-        from repro.dist.sharding import ShardingRules
-
-        d, m = _parse_mesh(args.mesh)
-        if d * m > jax.device_count():
-            raise SystemExit(
-                f"--mesh {args.mesh} needs {d * m} devices, have "
-                f"{jax.device_count()} (host-device forcing happens only "
-                f"when run as a script; check XLA_FLAGS)")
-        mesh = jax.make_mesh((d, m), ("data", "model"))
-        # serving keeps dense weights replicated over data (no FSDP):
-        # decode is latency-bound and the weight gathers would dominate
-        rules = ShardingRules.for_mesh(mesh, fsdp=False)
-        print(f"[serve] mesh {d}x{m} (data x model) over "
-              f"{jax.device_count()} devices")
+        rules = mesh_rules(args.mesh)
+        print(f"[serve] mesh {args.mesh} (data x model) over "
+              f"{rules.mesh.devices.size} {jax.devices()[0].platform} "
+              f"devices")
 
     cfg = configs.get(args.arch, smoke=args.smoke)
     policy = policy_named(args.policy) if args.policy else None

@@ -30,7 +30,9 @@ import jax.numpy as jnp
 
 from repro import configs
 from repro.data import DataConfig, make_stream
+from repro.dist import make_mesh
 from repro.dist.sharding import ShardingRules
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.optim import OptConfig, adamw_init
 from repro.train import LoopConfig, TrainConfig, TrainLoop, make_train_step
@@ -54,6 +56,7 @@ def main() -> int:
                     help="'local': 1D data mesh over visible devices")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = configs.get(args.arch, smoke=args.smoke)
     key = jax.random.PRNGKey(args.seed)
     params = M.init_params(key, cfg)
@@ -68,7 +71,7 @@ def main() -> int:
 
     rules = None
     if args.mesh == "local" and jax.device_count() > 1:
-        mesh = jax.make_mesh((jax.device_count(),), ("data",))
+        mesh = make_mesh((jax.device_count(),), ("data",))
         rules = ShardingRules.for_mesh(mesh)
 
     step = make_train_step(cfg, tcfg)

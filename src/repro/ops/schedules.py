@@ -4,8 +4,9 @@ Replaces the hard-coded ``block_q=128, block_k=512`` constants that used to
 live in ``kernels/ops.py``.  The table (``schedules.json`` next to this
 module) is a small measured artifact produced by ``benchmarks/ops_autotune.py``
 and shipped with sane defaults for both the CPU ``interpret`` backend (what
-CI measures) and ``tpu`` (Mosaic lowering; falls back to the interpret
-entries when a key is absent).
+CI measures) and ``tpu`` (Mosaic lowering).  Each backend's section stands
+alone: every kernel has a ``tpu`` entry that compiles at the M³ViT widths,
+so a TPU never runs the tiles tuned for the CPU interpreter.
 
 Resolution order for a block size, strongest last:
 
@@ -74,8 +75,6 @@ def schedule_for(op: str, impl: str, dims: Optional[dict] = None,
     key = f"{op}.{impl}"
     bk = backend or backend_key()
     entry = backends.get(bk, {}).get(key)
-    if entry is None and bk != "interpret":
-        entry = backends.get("interpret", {}).get(key)
     if entry is None:
         return {}
     blocks = dict(entry.get("defaults", {}))

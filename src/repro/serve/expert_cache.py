@@ -1020,6 +1020,9 @@ class PagedMoE:
         # count, lookahead submissions, fence stall) — the paged layer's
         # contribution to the serve-time stall/overlap reports
         self.last_timeline: list[dict] = []
+        # the most recent forward's routing, (groups, g, k) per field: the
+        # expert sets each token was dispatched to, for parity checks
+        self.last_routing: Optional[R.Routing] = None
         self.gate = jnp.asarray(params["gate"])
         gb = params.get("gate_bias")   # optional (tasks, E) logit bias
         self.gate_bias = None if gb is None else jnp.asarray(gb)
@@ -1138,10 +1141,8 @@ class PagedMoE:
 
         def finish(routing, rows_acc, real):
             def per_group(r, rows, rm):
-                # identical weighting + slot-sum order to routing.combine
-                w = (r.gate.reshape(-1)
-                     * r.valid.reshape(-1)).astype(rows.dtype)
-                y = (rows * w[:, None]).reshape(g, k, -1).sum(axis=1)
+                # the same weighted k-sum as routing.combine
+                y = R.combine_rows(rows, r)
                 aux = R.load_balance_loss(r.probs, r.expert, e, mask=rm)
                 return y, aux
             return jax.vmap(per_group)(routing, rows_acc, real)
@@ -1178,6 +1179,7 @@ class PagedMoE:
         if gate_b is None:
             gate_b = jnp.zeros((cfg.num_experts,), jnp.float32)
         routing, counts = self._route_fn(gate_w, gate_b, groups, real)
+        self.last_routing = routing
 
         counts_np = np.asarray(counts.sum(axis=0))
         self.usage.update(counts_np, task_id)

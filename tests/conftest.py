@@ -2,25 +2,8 @@
 container's single CPU device (the 512-device flag belongs ONLY to
 launch/dryrun.py)."""
 
-import importlib.util
-import os
-import sys
-
 import numpy as np
 import pytest
-
-# The image may not ship `hypothesis` (and repo rules forbid installing
-# it); fall back to the deterministic random-example stand-in so the
-# property tests still run.  See tests/_hypothesis_stub.py.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    _spec = importlib.util.spec_from_file_location(
-        "hypothesis",
-        os.path.join(os.path.dirname(__file__), "_hypothesis_stub.py"))
-    _mod = importlib.util.module_from_spec(_spec)
-    _spec.loader.exec_module(_mod)
-    sys.modules["hypothesis"] = _mod
 
 
 @pytest.fixture
@@ -33,6 +16,6 @@ def mesh():
     """Single-device (1, 1) ("data", "model") mesh — rule logic is
     device-count independent; the 512-way layouts are exercised by the
     dryrun and the forced-host-device subprocess tests."""
-    import jax
+    from repro.dist import make_mesh
 
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro.checkpoint import CheckpointManager, latest_step, restore, save
+from repro.dist import make_mesh
 
 
 def tree(seed=0):
@@ -241,7 +242,7 @@ class TestElasticRestore:
 
         t = {"w": jnp.arange(32, dtype=jnp.float32).reshape(8, 4)}
         save(str(tmp_path), 1, t)
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         sh = {"w": NamedSharding(mesh, P("data", None))}
         r = restore(str(tmp_path), 1, t, shardings=sh)
         assert r["w"].sharding == sh["w"]

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.dist import compress as C
+from repro.dist import make_mesh
 
 
 class TestQuantize:
@@ -61,7 +62,7 @@ class TestErrorFeedback:
     def test_compressed_psum_single_axis(self, rng):
         """compressed_psum inside shard_map on a 1-device mesh: identity
         reduce, EF state returned."""
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         g = {"w": jnp.asarray(rng.normal(size=(2048,)), jnp.float32)}
         e = C.init_error_state(g)
 
@@ -85,7 +86,7 @@ class TestStackedAllReduce:
     def test_mean_over_shards(self, rng):
         """Stacked wrapper: leading axis = DP shards (1 here), result is the
         shard mean with EF carried per shard."""
-        mesh = jax.make_mesh((1,), ("data",))
+        mesh = make_mesh((1,), ("data",))
         g = {"w": jnp.asarray(rng.normal(size=(1, 512)), jnp.float32)}
         e = {"w": jnp.zeros((1, 512), jnp.float32)}
         out_g, out_e = C.compressed_allreduce_stacked(g, e, mesh)

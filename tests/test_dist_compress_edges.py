@@ -6,10 +6,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.dist import compress as C
+from repro.dist import make_mesh
 
 
 def test_nonfinite_grad_does_not_poison_error_state():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     from jax.sharding import PartitionSpec as P
 
     fn = jax.shard_map(

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.dist import make_mesh
 from repro.dist.sharding import (
     ShardingRules, _trim_spec, constrain, current_rules,
     opt_state_shardings, use_rules)
@@ -19,6 +20,20 @@ from repro.dist.sharding import (
 def fake_mesh(**sizes):
     return types.SimpleNamespace(shape=dict(sizes),
                                  axis_names=tuple(sizes))
+
+
+@pytest.mark.parametrize("shape, axes", [
+    ((1,), ("data",)),
+    ((1, 1), ("data", "model")),
+    ((1, 1, 1), ("pod", "data", "model")),
+])
+def test_make_mesh_axes_are_auto(shape, axes):
+    """Explicit axes (jax.make_mesh's default) make the rules' sharding
+    constraints and the serving gathers raise ShardingTypeError."""
+    mesh = make_mesh(shape, axes)
+    assert mesh.axis_names == axes
+    assert dict(mesh.shape) == dict(zip(axes, shape))
+    assert mesh.axis_types == (jax.sharding.AxisType.Auto,) * len(axes)
 
 
 class TestTrimNonDivisible:

@@ -176,8 +176,8 @@ class TestShardedCacheBookkeeping:
         return {"w": rng.standard_normal((e, 4, 4)).astype(np.float32)}
 
     def _mesh(self):
-        import jax as _jax
-        return _jax.make_mesh((1, 1), ("data", "model"))
+        from repro.dist import make_mesh
+        return make_mesh((1, 1), ("data", "model"))
 
     def test_single_shard_matches_expert_cache(self):
         from repro.serve.expert_cache import ShardedExpertCache

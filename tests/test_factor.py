@@ -1,9 +1,7 @@
 """Factored-expert suite: FactoredTensor, the SVD-seeded converters, the
 tree walkers, and the xla_factored registry impls.
 
-Runs under real `hypothesis` when installed, else the deterministic
-random-example stand-in in tests/_hypothesis_stub.py (see conftest.py).
-Property obligations: reconstruction error is monotone non-increasing in
+Property obligations (checked with `hypothesis`): reconstruction error is monotone non-increasing in
 rank and exactly zero at full rank; rank-0 reconstructs the broadcast
 basis bit-exactly; butterfly seeding is exact on Monarch-structured
 residuals; non-finite inputs are rejected loudly; the factored dispatch

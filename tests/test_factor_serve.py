@@ -131,6 +131,7 @@ HEADER = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     os.environ["JAX_PLATFORMS"] = "cpu"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.dist import make_mesh
 """)
 
 
@@ -157,7 +158,7 @@ FACTORED_DIST_PARITY = HEADER + textwrap.dedent("""
         np.testing.assert_array_equal(np.asarray(y1), np.asarray(ref),
                                       err_msg=f"{kind} bits={bits} single")
         for m in (2,):
-            mesh = jax.make_mesh((1, m), ("data", "model"))
+            mesh = make_mesh((1, m), ("data", "model"))
             paged = PagedMoE(fparams, cfg, resident_fraction=0.5,
                              mesh=mesh)
             with use_policy(policy_named("xla_factored")):

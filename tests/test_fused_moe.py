@@ -30,7 +30,7 @@ from repro.core.gelu import (_cached_table, build_delta_table, exact_gelu,
                              lut_activation)
 from repro.core.online_softmax import merge_stats, online_max_sum
 from repro.kernels import ref
-from repro.kernels.runtime import default_interpret
+from repro.kernels.runtime import default_interpret, resolve_interpret
 
 # fused keeps f32 in VMEM end to end; in f32 it is bit-compatible with the
 # staged path up to dot reassociation
@@ -415,6 +415,13 @@ class TestInterpretModeReporting:
         assert rep["fallbacks"] and any(
             "compiled" in r or "interpret" in r
             for r in rep["fallbacks"][0]["reasons"])
+
+    @pytest.mark.parametrize("explicit", [None, True, False])
+    def test_resolve_interpret_honours_explicit_choice(self, explicit):
+        # only None asks the backend; False means compiled on any backend
+        # (a compile for a described TPU needs exactly that off-TPU)
+        want = default_interpret() if explicit is None else explicit
+        assert resolve_interpret(explicit) is want
 
 
 class TestModeledTraffic:

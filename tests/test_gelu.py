@@ -69,6 +69,36 @@ class TestAccuracy:
         assert abs(got - want) < 2.5e-3
 
 
+class TestKernelForms:
+    """The gather-free, erf-free forms Pallas kernel bodies use."""
+
+    def test_erf_rational_matches_float64_erf(self):
+        import math
+
+        x = np.linspace(-6, 6, 200001).astype(np.float32)
+        want = np.array([math.erf(float(v)) for v in x])
+        got = np.asarray(G.erf_rational(jnp.asarray(x)), np.float64)
+        assert np.abs(got - want).max() < 1e-6
+        lim = np.asarray(G.erf_rational(
+            jnp.asarray([np.inf, -np.inf, np.nan], jnp.float32)))
+        assert lim[0] == 1.0 and lim[1] == -1.0 and np.isnan(lim[2])
+
+    # 2048 / 1024 entries: whole table rows; 844: a ragged last row
+    @pytest.mark.parametrize("kind, rng_", [("gelu", 8.0), ("silu", 8.0),
+                                            ("gelu", 4.0), ("gelu", 3.3)])
+    def test_lanes_lookup_equals_gather(self, rng, kind, rng_):
+        table = G._cached_table(kind, G.LUT_STEP_LOG2, rng_)
+        y = rng.normal(size=(16, 256)).astype(np.float32) * 4
+        y[0, :4] = [np.inf, -np.inf, np.nan, 0.0]
+        y[1, :2] = [rng_, -rng_ * 2]                 # range edge, outside
+        want = G.lut_correction(jnp.asarray(y), jnp.asarray(table),
+                                G.LUT_STEP_LOG2)
+        got = G.lut_correction_lanes(
+            jnp.asarray(y), jnp.asarray(G.lut_table_lanes(table)),
+            G.LUT_STEP_LOG2, table.shape[0])
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 class TestDispatch:
     def test_get_activation(self):
         x = jnp.asarray([-1.0, 0.0, 2.0], jnp.float32)

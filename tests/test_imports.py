@@ -22,7 +22,8 @@ def _module_names():
 
 @pytest.mark.parametrize("name", _module_names())
 def test_import(name, monkeypatch):
-    # launch/dryrun mutates XLA_FLAGS at import for its own subprocess
-    # use; pin the var so the import can't leak it into this session
-    monkeypatch.setenv("XLA_FLAGS", os.environ.get("XLA_FLAGS", ""))
+    # launch/dryrun sets XLA_FLAGS and JAX_PLATFORMS at import for its own
+    # process; pin both so the import can't leak them into this session
+    for var in ("XLA_FLAGS", "JAX_PLATFORMS"):
+        monkeypatch.setenv(var, os.environ.get(var, ""))
     importlib.import_module(name)

@@ -13,11 +13,12 @@ import pytest
 from dataclasses import replace
 
 from repro.core import moe as M
+from repro.dist import make_mesh
 from repro.dist.sharding import ShardingRules, use_rules
 
 
 def test_ep_local_equals_grouped_single_device(rng):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = M.MoEConfig(d_model=32, d_ff=64, num_experts=4, top_k=2,
                       capacity_factor=4.0, group_size=64, impl="ep_local",
                       expert_kind="gelu")
@@ -45,12 +46,14 @@ def test_ep_local_no_mesh_falls_back(rng):
 MULTI_DEVICE_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.dist import make_mesh
     from dataclasses import replace
     from repro.core import moe as M
     from repro.dist.sharding import ShardingRules, use_rules
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(0)
     cfg = M.MoEConfig(d_model=32, d_ff=64, num_experts=8, top_k=2,
                       capacity_factor=4.0, group_size=64, impl="ep_local",

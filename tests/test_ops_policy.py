@@ -79,11 +79,14 @@ class TestPolicyScoping:
 
 
 class TestScheduleTable:
-    def test_shipped_table_covers_every_pallas_impl(self):
+    @pytest.mark.parametrize("backend", ["interpret", "tpu"])
+    def test_shipped_table_covers_every_pallas_impl(self, backend):
+        # each backend section stands alone (a TPU never borrows the
+        # interpreter's tiles), so each must cover every kernel impl
         for op, impls in ops.capability_matrix().items():
             for impl in (n for n in impls if n.startswith("pallas")):
-                blocks = ops.schedule_for(op, impl, {}, backend="interpret")
-                assert blocks, f"no interpret schedule entry for {op}.{impl}"
+                blocks = ops.schedule_for(op, impl, {}, backend=backend)
+                assert blocks, f"no {backend} schedule entry for {op}.{impl}"
                 assert all(isinstance(v, int) for v in blocks.values())
 
     def test_buckets_scale_blocks_with_shape(self):

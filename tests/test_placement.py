@@ -31,6 +31,7 @@ import numpy as np
 
 import pytest
 
+from repro.dist import make_mesh
 from repro.serve.expert_cache import ExpertCache, ExpertUsage
 from repro.serve.placement import (BudgetPolicy, ElasticPolicy, LRUPolicy,
                                    PlacementPlan, PlacementPolicy,
@@ -54,6 +55,7 @@ HEADER = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     os.environ["JAX_PLATFORMS"] = "cpu"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.dist import make_mesh
 """)
 
 
@@ -199,7 +201,7 @@ def test_sharded_reset_stats_clears_books_and_load():
     the per-interval load ledger; placement history is cumulative."""
     import jax
     from repro.serve.expert_cache import ShardedExpertCache
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cache = ShardedExpertCache(_toy_host(), 2, mesh)
     cache.prefetch(range(8))
     assert cache.prefetch_truncated == 6
@@ -323,7 +325,7 @@ SKEWED_STATIC = HEADER + textwrap.dedent("""
     ref, aref = moe_lib.apply_moe(params, cfg, x, task_id=0)
     out = {}
     for m in (2, 4):
-        mesh = jax.make_mesh((1, m), ("data", "model"))
+        mesh = make_mesh((1, m), ("data", "model"))
         paged = PagedMoE(params, cfg, resident_fraction=0.5, mesh=mesh,
                          placement="static")
         y, aux = paged(x, task_id=0)
@@ -372,7 +374,7 @@ ELASTIC_SKEW = HEADER + textwrap.dedent("""
            * 0.5).astype(jnp.float32) for i in range(6)]
     refs = [moe_lib.apply_moe(params, cfg, x, task_id=0)[0] for x in xs]
     for m in (2, 4):
-        mesh = jax.make_mesh((1, m), ("data", "model"))
+        mesh = make_mesh((1, m), ("data", "model"))
         pol = ElasticPolicy(rebalance_every=2, replicate_factor=1.2)
         paged = PagedMoE(params, cfg, resident_fraction=0.5, mesh=mesh,
                          placement=pol)
@@ -412,7 +414,7 @@ MIGRATE_TAG = HEADER + textwrap.dedent("""
     from repro.serve.placement import ElasticPolicy, PlacementPlan
     from repro.serve.transfer import FakeTransferEngine
 
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     rng = _np.random.default_rng(0)
     host = {"w": rng.standard_normal((8, 4, 4)).astype(_np.float32)}
     eng = FakeTransferEngine(latency_s=0.05, timeout_s=5.0)

@@ -1,9 +1,7 @@
 """Property-based quantization suite: the dist.compress int8 chunks, the
 repro.quant QTensor paths, int8 KV serving, quantized expert paging.
 
-Runs under real `hypothesis` when installed, else the deterministic
-random-example stand-in in tests/_hypothesis_stub.py (see conftest.py).
-Edge cases the properties must cover: all-zero rows, single-element
+Edge cases the `hypothesis` properties must cover: all-zero rows, single-element
 channels, extreme magnitudes, NaN rejection — with scale>0 and elementwise
 reconstruction-error bounds (half a quantization step).
 """

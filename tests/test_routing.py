@@ -122,3 +122,41 @@ class TestLoadBalance:
         skewed = float(R.load_balance_loss(probs_skew, skew, e))
         assert abs(uniform - 1.0) < 1e-5
         assert skewed > uniform * 2
+
+    def test_matches_f64_sum(self, rng):
+        """The aux loss is an f32-exact sum: within a few f32 ulps of the
+        same statistics summed in float64, masked rows excluded."""
+        t, e, k = 96, 16, 4
+        logits = jnp.asarray(rng.normal(size=(t, e)), jnp.float32)
+        expert, _, probs = R.route_topk(logits, k)
+        mask = jnp.asarray(rng.random(t) > 0.25)
+        got = float(R.load_balance_loss(probs, expert, e, mask=mask))
+        p64 = np.asarray(probs, np.float64)
+        m64 = np.asarray(mask, np.float64)
+        counts = np.zeros(e)
+        np.add.at(counts, np.asarray(expert).reshape(-1), np.repeat(m64, k))
+        denom = m64.sum()
+        want = e * np.sum(counts / (denom * k) * (m64 @ p64 / denom))
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+
+
+class TestCombineRows:
+    def test_matches_f64_weighted_sum(self, rng):
+        t, k, d = 24, 4, 32
+        r = R.route(jnp.asarray(rng.normal(size=(t, 8)), jnp.float32), k,
+                    capacity=t * k)
+        rows = jnp.asarray(rng.normal(size=(t * k, d)), jnp.float32)
+        got = np.asarray(R.combine_rows(rows, r))
+        w = np.asarray(r.gate, np.float64) * np.asarray(r.valid)
+        want = np.einsum("tk,tkd->td", w,
+                         np.asarray(rows, np.float64).reshape(t, k, d))
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+    def test_eager_equals_jit(self, rng):
+        t, k, d = 24, 4, 32
+        r = R.route(jnp.asarray(rng.normal(size=(t, 8)), jnp.float32), k,
+                    capacity=t * k)
+        rows = jnp.asarray(rng.normal(size=(t * k, d)), jnp.bfloat16)
+        np.testing.assert_array_equal(
+            np.asarray(R.combine_rows(rows, r), np.float32),
+            np.asarray(jax.jit(R.combine_rows)(rows, r), np.float32))

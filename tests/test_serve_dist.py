@@ -24,6 +24,8 @@ import numpy as np
 
 import pytest
 
+from repro.dist import make_mesh
+
 REPO = __file__.rsplit("/tests/", 1)[0]
 
 
@@ -41,6 +43,7 @@ HEADER = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     os.environ["JAX_PLATFORMS"] = "cpu"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.dist import make_mesh
 """)
 
 
@@ -50,7 +53,7 @@ PAGED_PARITY = HEADER + textwrap.dedent("""
 
     x32 = None
     for m in (2, 4):
-        mesh = jax.make_mesh((1, m), ("data", "model"))
+        mesh = make_mesh((1, m), ("data", "model"))
         for kind in ("gelu", "swiglu"):
             for dtype in (jnp.float32, jnp.bfloat16):
                 cfg = moe_lib.MoEConfig(
@@ -102,7 +105,7 @@ PAGED_QUANT_PARITY = HEADER + textwrap.dedent("""
         np.testing.assert_array_equal(np.asarray(y1), np.asarray(ref),
                                       err_msg=f"int{bits} single-device")
         for m in (2, 4):
-            mesh = jax.make_mesh((1, m), ("data", "model"))
+            mesh = make_mesh((1, m), ("data", "model"))
             with use_policy(policy_named("xla_int8")):
                 ym, _ = PagedMoE(qparams, cfg, resident_fraction=0.5,
                                  mesh=mesh)(x, task_id=0)
@@ -131,7 +134,7 @@ BUDGET_SCALING = HEADER + textwrap.dedent("""
     budget = 2 * per_expert          # 2 slots per device
     rates, residents = {}, {}
     for m in (1, 4):
-        mesh = jax.make_mesh((1, m), ("data", "model")) if m > 1 else None
+        mesh = make_mesh((1, m), ("data", "model")) if m > 1 else None
         paged = PagedMoE(params, cfg, budget_bytes=budget, mesh=mesh)
         for _ in range(3):
             paged(x, task_id=0)      # warm: every expert is routed to
@@ -170,7 +173,7 @@ DECODE_PARITY = HEADER + textwrap.dedent("""
         scfg = ServeConfig(max_len=32)
         ref = ServingEngine(cfg, params, scfg).generate(prompts, 6)
         for shape in ((1, 1), (2, 1), (2, 2), (1, 4)):
-            mesh = jax.make_mesh(shape, ("data", "model"))
+            mesh = make_mesh(shape, ("data", "model"))
             rules = ShardingRules.for_mesh(mesh, fsdp=False)
             eng = ServingEngine(cfg, params, scfg, rules=rules)
             out = eng.generate(prompts, 6)
@@ -207,7 +210,7 @@ SCHEDULER_PARITY = HEADER + textwrap.dedent("""
         return {r.rid: list(r.tokens) for r in done}
 
     ref = serve(None)
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     out = serve(ShardingRules.for_mesh(mesh, fsdp=False))
     assert ref == out, (ref, out)
     print("SCHEDULER_PARITY_OK")
@@ -232,7 +235,7 @@ VISION_PARITY = HEADER + textwrap.dedent("""
     imgs = np.asarray(jax.random.normal(
         jax.random.PRNGKey(1), (2, 128, 256, 3)), np.float32)
     ref = M3ViTServer(cfg, params, resident_fraction=0.5)
-    mesh = jax.make_mesh((1, 4), ("data", "model"))
+    mesh = make_mesh((1, 4), ("data", "model"))
     hybrid = M3ViTServer(cfg, params, resident_fraction=0.5, ep_mesh=mesh)
     full = M3ViTServer(cfg, params, resident_fraction=0.5,
                        rules=ShardingRules.for_mesh(mesh, fsdp=False))
@@ -258,7 +261,7 @@ ASYNC_SHARDED_PARITY = HEADER + textwrap.dedent("""
     from repro.serve.expert_cache import PagedMoE
     from repro.serve.transfer import FakeTransferEngine, TransferEngine
 
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     cfg = moe_lib.MoEConfig(d_model=32, d_ff=64, num_experts=8, top_k=2,
                             num_tasks=2, capacity_factor=2.0, group_size=64,
                             impl="grouped", expert_kind="swiglu")
@@ -310,7 +313,7 @@ SHARD_HANG = HEADER + textwrap.dedent("""
     from repro.serve.expert_cache import ShardedExpertCache
     from repro.serve.transfer import FakeTransferEngine, TransferTimeout
 
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     rng = _np.random.default_rng(0)
     host = {"w": rng.standard_normal((8, 4, 4)).astype(_np.float32)}
     eng = FakeTransferEngine(latency_s=0.1, timeout_s=5.0,
@@ -387,7 +390,7 @@ def test_engine_sharded_noop_mesh_in_process():
                                  cfg.vocab_size)
     scfg = ServeConfig(max_len=32)
     ref = ServingEngine(cfg, params, scfg).generate(prompts, 4)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     out = ServingEngine(cfg, params, scfg,
                         rules=ShardingRules.for_mesh(mesh, fsdp=False)
                         ).generate(prompts, 4)
