@@ -112,7 +112,7 @@ _SKEW_CHILD = textwrap.dedent("""
     s0 = paged.cache.stats()
     migrate_tags = (s0.get("transfer_tags") or {}).get("migrate")
 
-    paged.cache.reset_stats()
+    paged.reset_stats()
     rounds = []
     for _ in range(iters):
         t0 = time.perf_counter()
@@ -137,7 +137,7 @@ _SKEW_CHILD = textwrap.dedent("""
         "round_seconds": rounds,
         "parity_ok": parity_ok,
         "top20_share": float(hot[:k20].sum() / max(hot.sum(), 1e-9)),
-        "waves_per_forward": len(paged.last_timeline),
+        "waves_per_forward": paged.waves / paged.forwards,
         "hit_rate": s["hit_rate"],
         "bytes_paged": s["bytes_paged"],
         "shard_load": s["shard_load"],
@@ -177,7 +177,7 @@ def _child(repo: str, mesh: int, iters: int, zipf_a: float,
     p = out["placement"]
     print(f"[serve_dist] skew mesh {mesh} {mode}: "
           f"{out['tok_per_s']:.0f} tok/s, "
-          f"waves/fwd {out['waves_per_forward']}, "
+          f"waves/fwd {out['waves_per_forward']:.2f}, "
           f"imbalance {out['shard_load_imbalance']:.2f}, "
           f"swaps {p['plan_swaps']}, repl {p['replications']}")
     return out

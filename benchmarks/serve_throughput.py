@@ -251,13 +251,11 @@ def _async_section(quick, rows, out):
             rounds.append(time.perf_counter() - t0)
         best = sorted(rounds)[1] if len(rounds) > 1 else rounds[0]
         per_round = 2 * imgs.shape[0]
-        stats = server.cache_stats()
-        timeline = next(iter(server.paged.values())).last_timeline
-        return per_round / best, stats, timeline
+        return per_round / best, server.cache_stats()
 
-    sync_ips, sync_stats, _ = _measure(M3ViTServer(
+    sync_ips, sync_stats = _measure(M3ViTServer(
         cfg, params, expert_budget_bytes=budget))
-    async_ips, async_stats, timeline = _measure(M3ViTServer(
+    async_ips, async_stats = _measure(M3ViTServer(
         cfg, params, expert_budget_bytes=budget, async_paging=True))
 
     if "overlap_ratio" not in async_stats:
@@ -279,7 +277,8 @@ def _async_section(quick, rows, out):
         "async_cancelled": async_stats["async_cancelled"],
         "sync_hit_rate": sync_stats["hit_rate"],
         "async_hit_rate": async_stats["hit_rate"],
-        "wave_timeline": timeline,
+        "waves_per_forward": async_stats["waves"] / async_stats["forwards"],
+        "page_ins": async_stats["page_ins"],
         "accept_overlap_reported": True,
         "accept_async_speedup_1p15x": speedup >= 1.15,
     }

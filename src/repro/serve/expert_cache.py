@@ -40,6 +40,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -176,10 +177,10 @@ class ExpertCache:
                 n: jnp.zeros((self.max_resident,) + w.shape[1:], w.dtype)
                 for n, w in self.host.items()
             }
-            self._write = jax.jit(
-                lambda slots, new, r: {
-                    n: slots[n].at[r].set(new[n]) for n in slots},
-                donate_argnums=(0,))
+            def expert_slot_write(slots, new, r):
+                return {n: slots[n].at[r].set(new[n]) for n in slots}
+
+            self._write = jax.jit(expert_slot_write, donate_argnums=(0,))
             # batched variant: one donated store update for a whole fence
             # wave.  While compute holds the slots buffers the runtime
             # cannot donate in place and falls back to a copy — paying
@@ -201,10 +202,11 @@ class ExpertCache:
             # donation dependency on, the old buffers, so the commit
             # never has to wait for (or copy around) in-flight compute
             # that still holds them
-            self._write_full = jax.jit(
-                lambda *rows: {
-                    n: jnp.stack([r[n] for r in rows])
-                    for n in self.names})
+            def _write_full(*rows):
+                return {n: jnp.stack([r[n] for r in rows])
+                        for n in self.names}
+
+            self._write_full = jax.jit(_write_full)
         else:
             # bookkeeping-only mode: the slot store lives elsewhere (one
             # shard bank of a ShardedExpertCache); page-ins go through the
@@ -222,6 +224,7 @@ class ExpertCache:
         self.misses = 0
         self.evictions = 0
         self.bytes_paged = 0
+        self.page_ins = 0             # committed copies, demand or prefetch
         self.async_prefetches = 0     # transfers submitted by prefetch_async
         self.inflight_joins = 0       # in-flight transfers fenced by ensure
         self.async_cancelled = 0      # in-flight prefetches killed by evict
@@ -252,6 +255,7 @@ class ExpertCache:
 
     def reset_stats(self) -> None:
         self.hits = self.misses = self.evictions = self.bytes_paged = 0
+        self.page_ins = 0
         self.async_prefetches = self.inflight_joins = 0
         self.async_cancelled = 0
         self.prefetch_truncated = 0
@@ -261,6 +265,7 @@ class ExpertCache:
         out = {
             "hits": self.hits, "misses": self.misses,
             "evictions": self.evictions, "bytes_paged": self.bytes_paged,
+            "page_ins": self.page_ins,
             "hit_rate": self.hit_rate,
             "max_resident": self.max_resident,
             "resident_fraction": self.max_resident / self.num_experts,
@@ -310,11 +315,14 @@ class ExpertCache:
         if self._write_cb is not None:
             self._write_cb(slot, arrays)
         else:
-            dev = {n: jax.device_put(v) for n, v in arrays.items()}
-            self.slots = self._write(self.slots, dev, slot)
+            with TraceAnnotation("repro.paging.device_put"):
+                dev = {n: jax.device_put(v) for n, v in arrays.items()}
+            with TraceAnnotation("repro.paging.slot_write"):
+                self.slots = self._write(self.slots, dev, slot)
         self._slot_expert[slot] = expert
         self._lru[expert] = slot
         self.bytes_paged += self._expert_bytes
+        self.page_ins += 1
 
     def _host_rows(self, expert: int) -> dict[str, np.ndarray]:
         return {n: self.host[n][expert] for n in self.names}
@@ -323,12 +331,14 @@ class ExpertCache:
         """Synchronous demand page-in (also the misprediction fallback:
         an expert nobody prefetched still pages correctly — through the
         engine when one is attached, so its stall is accounted)."""
-        slot = self._reserve_slot(pinned)
-        new = self._host_rows(expert)
-        if self.engine is not None:
-            tr = self.engine.submit((self.label, expert), new, tag="demand")
-            new = self.engine.fence(tr)
-        self._commit(expert, slot, new)
+        with TraceAnnotation("repro.paging.page_in", expert=expert):
+            slot = self._reserve_slot(pinned)
+            new = self._host_rows(expert)
+            if self.engine is not None:
+                tr = self.engine.submit((self.label, expert), new,
+                                        tag="demand")
+                new = self.engine.fence(tr)
+            self._commit(expert, slot, new)
 
     def _submit_async(self, expert: int, pinned: set[int],
                       tag: str = "demand") -> Transfer:
@@ -343,15 +353,6 @@ class ExpertCache:
         self._slot_expert[slot] = expert
         self._lru[expert] = slot
         return tr
-
-    def _join(self, expert: int) -> None:
-        """Fence an in-flight transfer and commit it to its reserved slot.
-        May raise ``TransferTimeout`` (a hung transport is loud, never a
-        silent deadlock)."""
-        slot, tr = self._inflight.pop(expert)
-        payload = self.engine.fence(tr)
-        self._commit(expert, slot, payload)
-        self.inflight_joins += 1
 
     def _commit_batch(self, batch: list[tuple[int, int, dict]]) -> None:
         """Land a whole fence wave of ``(expert, slot, payload)`` in ONE
@@ -371,19 +372,21 @@ class ExpertCache:
         # A duplicated (slot, payload) pair writes identical values to
         # the same index, so the scatter result is unchanged
         k = len(batch)
-        if k == self.max_resident:
-            # every slot is being replaced: fresh store, old one dropped
-            by_slot = sorted(batch, key=lambda t: t[1])
-            self.slots = self._write_full(*(p for _, _, p in by_slot))
-        else:
-            full = batch + [batch[0]] * ((1 << (k - 1).bit_length()) - k)
-            idx = jnp.asarray([s for _, s, _ in full], jnp.int32)
-            self.slots = self._write_many(self.slots, idx,
-                                          *(p for _, _, p in full))
+        with TraceAnnotation("repro.paging.slot_write"):
+            if k == self.max_resident:
+                # every slot is being replaced: fresh store, old one dropped
+                by_slot = sorted(batch, key=lambda t: t[1])
+                self.slots = self._write_full(*(p for _, _, p in by_slot))
+            else:
+                full = batch + [batch[0]] * ((1 << (k - 1).bit_length()) - k)
+                idx = jnp.asarray([s for _, s, _ in full], jnp.int32)
+                self.slots = self._write_many(self.slots, idx,
+                                              *(p for _, _, p in full))
         for e, slot, _ in batch:
             self._slot_expert[slot] = e
             self._lru[e] = slot
             self.bytes_paged += self._expert_bytes
+            self.page_ins += 1
 
     def ensure_submit(self, expert_ids, record: bool = True) -> list[int]:
         """Async first half of ``ensure``: submit copies for every missing
@@ -414,16 +417,18 @@ class ExpertCache:
     def ensure_fence(self, expert_ids) -> None:
         """Fence+commit the in-flight members of ``expert_ids`` (the
         second half of the async ``ensure``).  Payloads are fenced one by
-        one but committed as a single batched store write; if a fence
-        raises (hung transport), everything fenced before it still
-        commits — then the timeout propagates, loud."""
+        one, each fence a page-in span (the wait for that expert's copy),
+        but committed as a single batched store write; if a fence raises
+        (hung transport), everything fenced before it still commits —
+        then the timeout propagates, loud."""
         batch: list[tuple[int, int, dict]] = []
         try:
             for e in expert_ids:
                 e = int(e)
                 if e in self._inflight:
                     slot, tr = self._inflight.pop(e)
-                    payload = self.engine.fence(tr)
+                    with TraceAnnotation("repro.paging.page_in", expert=e):
+                        payload = self.engine.fence(tr)
                     batch.append((e, slot, payload))
                     self.inflight_joins += 1
         finally:
@@ -445,20 +450,23 @@ class ExpertCache:
         (and counted as hits — the prediction converted demand paging into
         an already-flying copy).  Without an engine it is the synchronous
         PR-2 path, bit-for-bit."""
-        if self.engine is not None:
-            self.ensure_fence(self.ensure_submit(expert_ids, record=record))
-            return
-        needed = self._check_working_set(expert_ids)
-        pinned = set(needed)
-        for e in needed:
-            if e in self._lru:
-                self._lru.move_to_end(e)
-                if record:
-                    self.hits += 1
-            else:
-                if record:
-                    self.misses += 1
-                self._page_in(e, pinned)
+        with TraceAnnotation("repro.paging.ensure",
+                             experts=len(expert_ids)):
+            if self.engine is not None:
+                self.ensure_fence(self.ensure_submit(expert_ids,
+                                                     record=record))
+                return
+            needed = self._check_working_set(expert_ids)
+            pinned = set(needed)
+            for e in needed:
+                if e in self._lru:
+                    self._lru.move_to_end(e)
+                    if record:
+                        self.hits += 1
+                else:
+                    if record:
+                        self.misses += 1
+                    self._page_in(e, pinned)
 
     def _truncate_prefetch(self, expert_ids) -> list[int]:
         ids = list(dict.fromkeys(int(e) for e in expert_ids))
@@ -642,10 +650,12 @@ class ShardedExpertCache:
             for n, w in host.items()
         }
         out_sh = {n: a.sharding for n, a in self.slots.items()}
-        self._write = jax.jit(
-            lambda slots, new, s, r: {
-                n: slots[n].at[s, r].set(new[n]) for n in slots},
-            donate_argnums=(0,), out_shardings=out_sh)
+
+        def expert_slot_write(slots, new, s, r):
+            return {n: slots[n].at[s, r].set(new[n]) for n in slots}
+
+        self._write = jax.jit(expert_slot_write, donate_argnums=(0,),
+                              out_shardings=out_sh)
 
         # every book sees the FULL host store and keys by GLOBAL expert
         # id — which experts a shard may page is the plan's decision, not
@@ -655,9 +665,11 @@ class ShardedExpertCache:
 
         def _book(s: int) -> ExpertCache:
             def write_cb(slot, new, _s=s):
-                dev = {n: jax.device_put(v) for n, v in new.items()}
-                self.slots = self._write(self.slots, dev,
-                                         jnp.int32(_s), jnp.int32(slot))
+                with TraceAnnotation("repro.paging.device_put"):
+                    dev = {n: jax.device_put(v) for n, v in new.items()}
+                with TraceAnnotation("repro.paging.slot_write"):
+                    self.slots = self._write(self.slots, dev,
+                                             jnp.int32(_s), jnp.int32(slot))
 
             return ExpertCache(full, rs, write_cb=write_cb,
                                transfer_engine=transfer_engine,
@@ -693,6 +705,7 @@ class ShardedExpertCache:
     misses = property(lambda self: self._sum("misses"))
     evictions = property(lambda self: self._sum("evictions"))
     bytes_paged = property(lambda self: self._sum("bytes_paged"))
+    page_ins = property(lambda self: self._sum("page_ins"))
     prefetch_truncated = property(
         lambda self: self._sum("prefetch_truncated"))
 
@@ -732,6 +745,7 @@ class ShardedExpertCache:
         out = {
             "hits": self.hits, "misses": self.misses,
             "evictions": self.evictions, "bytes_paged": self.bytes_paged,
+            "page_ins": self.page_ins,
             "hit_rate": self.hit_rate,
             "max_resident": self.max_resident,       # per shard
             "num_shards": self.num_shards,
@@ -786,15 +800,18 @@ class ShardedExpertCache:
         page-ins overlap each other (and the all-to-all dispatch of the
         wave already on the device): the wave stalls for the slowest
         shard's copy, not the sum of all shards' copies."""
-        by = self._by_shard(expert_ids)
-        if self.engine is not None:
-            pending = {s: self.books[s].ensure_submit(local, record=record)
-                       for s, local in by.items()}
-            for s, fence_ids in pending.items():
-                self.books[s].ensure_fence(fence_ids)
-            return
-        for s, local in by.items():
-            self.books[s].ensure(local, record=record)
+        with TraceAnnotation("repro.paging.ensure",
+                             experts=len(expert_ids)):
+            by = self._by_shard(expert_ids)
+            if self.engine is not None:
+                pending = {s: self.books[s].ensure_submit(local,
+                                                          record=record)
+                           for s, local in by.items()}
+                for s, fence_ids in pending.items():
+                    self.books[s].ensure_fence(fence_ids)
+                return
+            for s, local in by.items():
+                self.books[s].ensure(local, record=record)
 
     def prefetch(self, expert_ids) -> None:
         """Warm each shard's bank with its share of ``expert_ids`` (global
@@ -904,6 +921,9 @@ class PagedMoE:
     gate weights and sums the k slots per token in the same order as
     ``routing.combine`` — so splitting into waves never changes the
     floating-point result.
+
+    ``layer`` is the layer's index in its model: the ``layer`` stat of its
+    ``repro.moe.call`` span.
     """
 
     def __init__(self, params, cfg: MoEConfig,
@@ -913,12 +933,13 @@ class PagedMoE:
                  budget_bytes: Optional[int] = None,
                  mesh=None, ep_axis: str = "model",
                  transfer_engine=None,
-                 placement=None):
+                 placement=None, layer: int = 0):
         if cfg.impl not in ("grouped", "onehot"):
             raise ValueError(
                 "PagedMoE pages the grouped/onehot expert paths (ep_local "
                 "keeps all experts resident — nothing to page)")
         self.cfg = cfg
+        self.layer = layer
         # expert-parallel mode: a mesh whose ep_axis has >1 shards switches
         # the cache to per-shard banks and the waves to the one-hot GSPMD
         # dispatch (all-to-all moves tokens; experts stay put)
@@ -1016,10 +1037,9 @@ class PagedMoE:
                                      transfer_engine=transfer_engine,
                                      pinned=pinned, policy=self.policy)
         self._forwards = 0   # rebalance cadence counter (policy-driven)
-        # per-wave record of the most recent forward (wave id, expert
-        # count, lookahead submissions, fence stall) — the paged layer's
-        # contribution to the serve-time stall/overlap reports
-        self.last_timeline: list[dict] = []
+        # forwards and expert waves run since the last reset_stats()
+        self.forwards = 0
+        self.waves = 0
         # the most recent forward's routing, (groups, g, k) per field: the
         # expert sets each token was dispatched to, for parity checks
         self.last_routing: Optional[R.Routing] = None
@@ -1154,7 +1174,16 @@ class PagedMoE:
 
     # ------------------------------------------------------------- forward
 
+    def reset_stats(self) -> None:
+        """Zero the forward and wave counts and the cache's counters."""
+        self.forwards = self.waves = 0
+        self.cache.reset_stats()
+
     def __call__(self, x: jax.Array, task_id: int = 0):
+        with TraceAnnotation("repro.moe.call", layer=self.layer):
+            return self._forward(x, task_id)
+
+    def _forward(self, x: jax.Array, task_id: int):
         cfg = self.cfg
         orig_shape = x.shape
         d = x.shape[-1]
@@ -1170,56 +1199,58 @@ class PagedMoE:
         if getattr(self, "_built_for", None) != (g, capacity):
             self._build(g, capacity)
 
-        gate_w = self.gate
-        if gate_w.ndim == 3:
-            gate_w = gate_w[int(task_id)]
-        gate_b = self.gate_bias
-        if gate_b is not None and gate_b.ndim == 2:
-            gate_b = gate_b[int(task_id)]
-        if gate_b is None:
-            gate_b = jnp.zeros((cfg.num_experts,), jnp.float32)
-        routing, counts = self._route_fn(gate_w, gate_b, groups, real)
+        with TraceAnnotation("repro.moe.route"):
+            gate_w = self.gate
+            if gate_w.ndim == 3:
+                gate_w = gate_w[int(task_id)]
+            gate_b = self.gate_bias
+            if gate_b is not None and gate_b.ndim == 2:
+                gate_b = gate_b[int(task_id)]
+            if gate_b is None:
+                gate_b = jnp.zeros((cfg.num_experts,), jnp.float32)
+            routing, counts = self._route_fn(gate_w, gate_b, groups, real)
         self.last_routing = routing
 
-        counts_np = np.asarray(counts.sum(axis=0))
-        self.usage.update(counts_np, task_id)
-        if self.mesh is not None:
-            # per-shard load evidence for the elastic policy (and the
-            # imbalance numbers in stats()) — recorded under the CURRENT
-            # plan, i.e. where this forward's tokens actually go
-            self.cache.record_load(counts_np)
-        needed = [int(i) for i in np.nonzero(counts_np)[0]]
-        # wave order: already-resident experts first, so warm residency
-        # (prefetch or the previous batch) turns into demand hits
-        res = set(self.cache.resident)
-        needed.sort(key=lambda i: (i not in res, i))
+        with TraceAnnotation("repro.moe.readback"):
+            counts_np = np.asarray(counts.sum(axis=0))
+        with TraceAnnotation("repro.moe.plan"):
+            self.usage.update(counts_np, task_id)
+            if self.mesh is not None:
+                # per-shard load evidence for the elastic policy (and the
+                # imbalance numbers in stats()) — recorded under the
+                # CURRENT plan, i.e. where this forward's tokens actually go
+                self.cache.record_load(counts_np)
+            needed = [int(i) for i in np.nonzero(counts_np)[0]]
+            # wave order: already-resident experts first, so warm residency
+            # (prefetch or the previous batch) turns into demand hits
+            res = set(self.cache.resident)
+            needed.sort(key=lambda i: (i not in res, i))
+            waves = self._plan_waves(needed)
 
         n = groups.shape[0]
         rows = jnp.zeros((n, g * cfg.top_k, d), groups.dtype)
-        waves = self._plan_waves(needed)
         eng = self.engine
-        timeline: list[dict] = []
         for k, wave_ids in enumerate(waves):
-            stall0 = eng.stats.stall_s if eng is not None else 0.0
             # fence point: everything this wave dereferences must have
             # landed — in-flight lookahead copies commit here, anything
             # mispredicted demand-pages (correctness never depends on
             # prediction quality)
             self.cache.ensure(wave_ids)
-            table, rep_counts = self.cache.replica_table()
-            # masking contract: every id this wave dereferences must be
-            # resident on at least one of its plan shards (the table
-            # carries -1 sentinels for everything else)
-            assert (rep_counts[wave_ids] >= 1).all(), \
-                f"wave ids {wave_ids} not all resident: " \
-                f"{rep_counts[wave_ids]}"
-            mask = np.zeros((cfg.num_experts,), bool)
-            mask[wave_ids] = True
-            rows = self._wave_fn(groups, routing, self.cache.slots,
-                                 self.cache.pinned, jnp.asarray(mask),
-                                 jnp.asarray(table),
-                                 jnp.asarray(rep_counts), rows)
-            prefetched: list[int] = []
+            with TraceAnnotation("repro.moe.launch", wave=k,
+                                 experts=len(wave_ids)):
+                table, rep_counts = self.cache.replica_table()
+                # masking contract: every id this wave dereferences must be
+                # resident on at least one of its plan shards (the table
+                # carries -1 sentinels for everything else)
+                assert (rep_counts[wave_ids] >= 1).all(), \
+                    f"wave ids {wave_ids} not all resident: " \
+                    f"{rep_counts[wave_ids]}"
+                mask = np.zeros((cfg.num_experts,), bool)
+                mask[wave_ids] = True
+                rows = self._wave_fn(groups, routing, self.cache.slots,
+                                     self.cache.pinned, jnp.asarray(mask),
+                                     jnp.asarray(table),
+                                     jnp.asarray(rep_counts), rows)
             if eng is not None:
                 if k + 1 < len(waves):
                     # router lookahead inside the batch: the wave launch
@@ -1227,23 +1258,20 @@ class PagedMoE:
                     # submitted NOW and ride behind wave k's compute —
                     # the double-buffer. Evicted slots are safe to retarget
                     # (commits happen only at the next fence point).
-                    prefetched = self.cache.prefetch_async(waves[k + 1])
+                    self.cache.prefetch_async(waves[k + 1])
                 eng.on_wave()   # virtual-clock transports model the
                 #                 wave's compute time passing here
-            timeline.append({
-                "wave": k, "experts": len(wave_ids),
-                "lookahead_submitted": len(prefetched),
-                "stall_s": (eng.stats.stall_s - stall0) if eng is not None
-                else 0.0,
-            })
-        self.last_timeline = timeline
+        self.forwards += 1
+        self.waves += len(waves)
         # rebalance point: ALL of this forward's waves have launched, the
         # next forward has not started — the only place a plan may swap.
         # Migration page-ins submitted here stream behind the combine and
         # the trunk layers that follow (tagged "migrate" in the ledger).
         self._maybe_rebalance()
-        y, aux = self._finish_fn(routing, rows, real)
-        y = y.reshape(-1, d)[:t_total].reshape(orig_shape).astype(x.dtype)
+        with TraceAnnotation("repro.moe.finish"):
+            y, aux = self._finish_fn(routing, rows, real)
+            y = y.reshape(-1, d)[:t_total].reshape(orig_shape).astype(
+                x.dtype)
 
         if cfg.num_shared_experts:
             gshared = unified_linear(x, self.shared["shared_wg"],

@@ -61,6 +61,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.dist.sharding import ShardingRules, use_rules
@@ -98,6 +99,9 @@ class Request:
     t_done: Optional[float] = None
     preemptions: int = 0            # times this request's slot was parked
     prefix_hit_tokens: int = 0      # prefill tokens skipped via prefix cache
+    # vision: the bucket forward that served it (the ``step`` stat of its
+    # ``repro.vision.quantum`` span, whose ``task`` is task_id)
+    step: Optional[int] = None
 
     @property
     def ttft(self) -> float:
@@ -815,6 +819,10 @@ class Scheduler:
     def step(self) -> bool:
         """One scheduling quantum.  Returns False when nothing was runnable
         (e.g. every remaining arrival is in the future)."""
+        with TraceAnnotation("repro.sched.step"):
+            return self._step()
+
+    def _step(self) -> bool:
         now = self.now()
         if self.mixed:
             bucket = self._bucket(None)
@@ -843,7 +851,7 @@ class Scheduler:
                 bucket = self._bucket(task)
                 q = self.queues[task]
 
-                def admit():
+                def fill():
                     if self.slo is None:
                         while bucket.free_slots and q \
                                 and q[0].arrival <= self.now():
@@ -875,6 +883,10 @@ class Scheduler:
                             break
                         self.finished.extend(bucket.admit(r, self.now()))
 
+                def admit():
+                    with TraceAnnotation("repro.sched.admit"):
+                        fill()
+
                 admit()
                 # router lookahead across buckets: submit the NEXT task's
                 # usage-hot experts before this quantum launches, so their
@@ -885,7 +897,8 @@ class Scheduler:
                 if la is not None:
                     nxt = self._peek_next_task(task, now)
                     if nxt is not None:
-                        la(nxt)
+                        with TraceAnnotation("repro.sched.lookahead"):
+                            la(nxt)
                 self.finished.extend(bucket.run_quantum(
                     self.quantum, self.now, admit_cb=admit))
                 return True

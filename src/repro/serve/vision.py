@@ -21,6 +21,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs import m3vit as MV
 from repro.configs.base import ArchConfig
@@ -128,7 +129,7 @@ class M3ViTServer:
                         resident_fraction=resident_fraction,
                         budget_bytes=expert_budget_bytes,
                         mesh=mesh, transfer_engine=self.engine,
-                        placement=placement)
+                        placement=placement, layer=i)
             for i, kind in enumerate(self.kinds) if kind == "attn_moe"
         }
 
@@ -155,19 +156,20 @@ class M3ViTServer:
             with use_policy(cfg.policy):
                 return V.embed_patches(prm, img, cfg)
 
+        def final_norm(prm, x):
+            return L.apply_norm(prm["final_norm"], x, cfg)
+
+        def head_for(t):
+            def task_head(prm, f):
+                with use_policy(cfg.policy):
+                    return V.apply_head(prm, f, t)
+            return jax.jit(task_head)
+
         self._embed = jax.jit(embed)
         self._dense = jax.jit(dense_block)
         self._moe_pre = jax.jit(moe_pre)
-        self._final = jax.jit(
-            lambda prm, x: L.apply_norm(prm["final_norm"], x, cfg))
-        def head(prm, f, t):
-            with use_policy(cfg.policy):
-                return V.apply_head(prm, f, t)
-
-        self._heads = {
-            t: jax.jit(lambda prm, f, _t=t: head(prm, f, _t))
-            for t in MV.TASKS
-        }
+        self._final = jax.jit(final_norm)
+        self._heads = {t: head_for(t) for t in MV.TASKS}
 
     def infer(self, images, task) -> np.ndarray:
         """images: (B, H, W, 3) f32 or (B, T, d) patch embeddings.
@@ -176,22 +178,28 @@ class M3ViTServer:
         # rules scope covers the jit traces below, so the dense blocks'
         # constrain() calls bind to the serving mesh
         with use_rules(self.rules):
-            x = self._embed(self.params, jnp.asarray(images))
-            b, s = x.shape[0], x.shape[1]
-            pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None],
-                                   (b, s))
+            with TraceAnnotation("repro.vision.embed"):
+                x = self._embed(self.params, jnp.asarray(images))
+                b, s = x.shape[0], x.shape[1]
+                pos = jnp.broadcast_to(
+                    jnp.arange(s, dtype=jnp.int32)[None], (b, s))
             for i, kind in enumerate(self.kinds):
                 bp = self.layer_params[i]
                 if kind == "attn_moe":
-                    xr, h = self._moe_pre(bp, x, pos)
+                    with TraceAnnotation("repro.vision.moe_pre", layer=i):
+                        xr, h = self._moe_pre(bp, x, pos)
                     with use_policy(self.cfg.policy):
                         y, _ = self.paged[i](h, task_id=task_id)
                     x = xr + y
                 else:
-                    x = self._dense(bp, x, pos)
-            feats = self._final(self.params, x)
-            return np.asarray(
-                self._heads[MV.TASKS[task_id]](self.params, feats))
+                    with TraceAnnotation("repro.vision.dense", layer=i):
+                        x = self._dense(bp, x, pos)
+            with TraceAnnotation("repro.vision.final"):
+                feats = self._final(self.params, x)
+            with TraceAnnotation("repro.vision.head"):
+                pred = self._heads[MV.TASKS[task_id]](self.params, feats)
+            with TraceAnnotation("repro.vision.readback"):
+                return np.asarray(pred)
 
     def prefetch(self, task_id: int) -> None:
         """Warm every MoE layer's expert cache with the task's hot set —
@@ -207,7 +215,10 @@ class M3ViTServer:
     lookahead = prefetch
 
     def cache_stats(self) -> dict[str, Any]:
-        agg = {"hits": 0, "misses": 0, "evictions": 0, "bytes_paged": 0}
+        # forwards and waves are paged-layer counts, summed over the MoE
+        # layers (a served batch is one forward of every layer)
+        agg = {"hits": 0, "misses": 0, "evictions": 0, "bytes_paged": 0,
+               "page_ins": 0, "forwards": 0, "waves": 0}
         async_agg = {"async_prefetches": 0, "inflight_joins": 0,
                      "async_cancelled": 0}
         frac = 0.0
@@ -215,8 +226,11 @@ class M3ViTServer:
         placement: dict[str, Any] = {}
         for paged in self.paged.values():
             s = paged.cache.stats()
-            for k in ("hits", "misses", "evictions", "bytes_paged"):
+            for k in ("hits", "misses", "evictions", "bytes_paged",
+                      "page_ins"):
                 agg[k] += s[k]
+            agg["forwards"] += paged.forwards
+            agg["waves"] += paged.waves
             for k in async_agg:
                 async_agg[k] += s.get(k, 0)
             frac = s["resident_fraction"]
@@ -258,10 +272,11 @@ class M3ViTServer:
         return agg
 
     def reset_stats(self) -> None:
-        """Zero cache counters AND the shared transfer ledger — call at a
-        measurement boundary so stall_s/overlap_ratio cover one interval."""
+        """Zero cache and layer counters AND the shared transfer ledger —
+        call at a measurement boundary so stall_s/overlap_ratio cover one
+        interval."""
         for paged in self.paged.values():
-            paged.cache.reset_stats()
+            paged.reset_stats()
         if self.engine is not None:
             self.engine.reset_stats()
 
@@ -309,21 +324,28 @@ class VisionTaskBucket:
             admit_cb()      # top up the batch before launching it
         if not self.staged:
             return []
-        server = self.backend.server
-        server.prefetch(self.task_id)
         batch = self.staged
         self.staged = []
-        imgs = np.stack([np.asarray(r.prompt) for r in batch])
-        if imgs.shape[0] < self.slots:   # fixed batch shape: one compile
-            pad = np.repeat(imgs[:1], self.slots - imgs.shape[0], axis=0)
-            imgs = np.concatenate([imgs, pad], axis=0)
-        preds = server.infer(imgs, self.task_id)
+        step = self.steps
+        with TraceAnnotation("repro.vision.quantum", step=step,
+                             task=self.task_id, batch=len(batch)):
+            server = self.backend.server
+            with TraceAnnotation("repro.vision.prefetch"):
+                server.prefetch(self.task_id)
+            with TraceAnnotation("repro.vision.stack"):
+                imgs = np.stack([np.asarray(r.prompt) for r in batch])
+                if imgs.shape[0] < self.slots:  # fixed batch: one compile
+                    pad = np.repeat(imgs[:1], self.slots - imgs.shape[0],
+                                    axis=0)
+                    imgs = np.concatenate([imgs, pad], axis=0)
+            preds = server.infer(imgs, self.task_id)
         now = now_fn()
         self.steps += 1
         self.slot_steps += len(batch)
         for i, req in enumerate(batch):
             req.result = preds[i]
             req.t_first = req.t_done = now
+            req.step = step
         return batch
 
 
