@@ -11,12 +11,18 @@ The paper approximates ``GELU(x) ~= ReLU(x) - delta(x)`` where the correction
     correction underflows), outside that range ReLU(x) is returned directly;
   * the step is a **negative power of two**, so indexing is a bit shift.
 
-TPU adaptation: the table lives in VMEM and the lookup is a vectorized gather
-on the VPU.  The same construction generalizes to any activation that is a
-small correction on a cheap base function; SwiGLU architectures use SiLU, whose
-correction ``delta(x) = ReLU(x) - SiLU(x) = ReLU(-x)*sigmoid(x) + ...`` is an
-**odd-symmetric-about-origin** residual: in fact ReLU(x) - SiLU(x) is even too
-(see ``_silu_delta``), so the identical half-table trick applies.
+TPU adaptation: the table is a tabulation of a closed form,
+``delta(q) = q * Phi(-q) = q * erfc(q / sqrt(2)) / 2`` for GELU.  On the XLA
+path ``lut_activation`` rounds |x| to the table's grid exactly as the lookup
+does and evaluates that entry in registers: a few VPU operations that fuse
+into the producing GEMM's epilogue, where an XLA gather from the table in HBM
+runs element by element.  Pallas kernel bodies, which have no ``erf``, keep
+the table in VMEM and read it with ``lut_correction_lanes``.  ``lut_correction``
+reads the table too and is the oracle both forms are held to.  The same
+construction generalizes to any activation that is a small correction on a
+cheap base function: SwiGLU architectures use SiLU, whose correction
+``ReLU(x) - SiLU(x) = |x| * sigmoid(-|x|)`` is even too, so the identical
+half-table trick applies.
 
 ``max_abs_err`` for the default table (step 2^-8, range 8) is ~2e-5 for GELU —
 validated by tests against the exact erf formulation, and by an end-task check
@@ -37,6 +43,7 @@ __all__ = [
     "exact_silu",
     "build_delta_table",
     "lut_correction",
+    "delta_closed_form",
     "lut_gelu",
     "lut_silu",
     "lut_activation",
@@ -112,15 +119,27 @@ def _cached_table(kind: str, step_log2: int, rng: float) -> np.ndarray:
 def lut_correction(y, table, step_log2: int):
     """ReLU(y) − δ(|y|) with non-finite inputs handled like the exact forms.
 
-    Shared by the jnp path and every kernel epilogue.  The index is clamped
-    to the table (NaN/Inf used to flow through ``round().astype(int32)``
-    into an implementation-defined — possibly negative, wrapping — gather
-    index); non-finite y bypass the table entirely and return
+    The table read with a gather: the oracle that ``lut_activation``'s
+    closed form and the kernels' ``lut_correction_lanes`` are held to.
+    The index is clamped to the table (NaN/Inf used to flow through
+    ``round().astype(int32)`` into an implementation-defined — possibly
+    negative, wrapping — gather index); non-finite y bypass the table
+    entirely and return
     ``y * 0.5 * (1 + sign(y))``, which reproduces the exact-activation
     limits: +inf → +inf, −inf → NaN (as ``exact_gelu``/``exact_silu`` give),
     NaN → NaN.  ``y`` and ``table`` must share a float dtype.
     """
-    n = table.shape[0]
+    return _relu_minus_delta(y, step_log2, table.shape[0],
+                             lambda idx: jnp.take(table, idx))
+
+
+def _relu_minus_delta(y, step_log2: int, n: int, delta_at):
+    """ReLU(y) − δ at the nearest of the n grid points k·2^step_log2.
+
+    ``delta_at(idx)`` gives δ at int32 table indices; everything else —
+    rounding, the clamp, truncation at the range, non-finite handling —
+    is shared by the table and closed-form paths.
+    """
     scale = 2.0 ** (-step_log2)
     ay = jnp.abs(y)
     finite = jnp.isfinite(y)
@@ -128,9 +147,22 @@ def lut_correction(y, table, step_log2: int):
     # garbage); the clamped index only matters when in_range holds
     in_range = finite & (ay * scale < n)
     idx = jnp.clip(jnp.round(ay * scale).astype(jnp.int32), 0, n - 1)
-    delta = jnp.where(in_range, jnp.take(table, idx), 0.0)
+    delta = jnp.where(in_range, delta_at(idx), 0.0)
     out = jnp.maximum(y, 0.0) - delta
     return jnp.where(finite, out, y * 0.5 * (1.0 + jnp.sign(y)))
+
+
+def delta_closed_form(q, kind: str):
+    """δ(q) for q >= 0 in q's float dtype: the closed form the table holds.
+
+    GELU: q·Φ(−q) = ½·q·erfc(q/√2) (``erfc``, not 1 − erf, which cancels
+    for large q); SiLU: q·σ(−q).
+    """
+    if kind == "gelu":
+        return 0.5 * q * jax.lax.erfc(q * np.float32(1.0 / math.sqrt(2.0)))
+    if kind == "silu":
+        return q * jax.nn.sigmoid(-q)
+    raise ValueError(f"unknown LUT activation kind: {kind}")
 
 
 LANES = 128
@@ -216,22 +248,32 @@ def lut_correction_lanes(y, table_rows, step_log2: int, n: int):
 def lut_activation(
     x: jax.Array,
     kind: str = "gelu",
-    table: jax.Array | None = None,
     step_log2: int = LUT_STEP_LOG2,
     rng: float = LUT_RANGE,
 ) -> jax.Array:
-    """ReLU(x) - delta(|x|) with delta from the LUT (paper Eq. 4).
+    """ReLU(x) - delta(|x|) with delta the LUT's entry (paper Eq. 4).
 
     Index = |x| / 2^step_log2 = |x| * 2^-step_log2 — the bit-shift of the
     paper.  Values beyond the truncated range return ReLU(x) exactly (delta=0).
     Nearest-entry rounding matches the fixed-point hardware behaviour; the
     table is dense enough (2^-8 step) that linear interpolation is unneeded —
-    tests quantify both.
+    tests quantify both.  The entry is evaluated in float32 from its closed
+    form (``delta_closed_form``) rather than gathered from the table: it
+    matches the float32 table to ~1e-7 and lowers with no gather.
     """
-    if table is None:
-        table = jnp.asarray(_cached_table(kind, step_log2, float(rng)))
-    y = lut_correction(x.astype(jnp.float32), table.astype(jnp.float32),
-                       step_log2)
+    n = int(rng / 2.0**step_log2)       # the table's length
+    step = np.float32(2.0**step_log2)
+
+    def delta_at(idx):
+        d = delta_closed_form(idx.astype(jnp.float32) * step, kind)
+        # δ < 1, so the clamp changes no value; it keeps δ's last product
+        # from being contracted into ReLU(y) − δ as an FMA, which a fused
+        # (jitted) program does and op-by-op evaluation does not: without
+        # it the same y rounds differently in the two, as a table entry
+        # never does
+        return jnp.minimum(d, 1.0)
+
+    y = _relu_minus_delta(x.astype(jnp.float32), step_log2, n, delta_at)
     return y.astype(x.dtype)
 
 

@@ -6,17 +6,20 @@ each kernel of the M³ViT serving path at M³ViT widths (d 192, f 768, 16
 experts top-4, 3 heads of 64, 128-token groups) for one chip of a ``v5e:2x2``
 topology, with ``interpret=False`` and the ``tpu`` tile schedule, and check
 that the compiled program holds the Mosaic kernel (``tpu_custom_call``).
-Nothing runs, so no chip is needed; where the TPU compiler cannot describe
-the topology the tests skip.
+The default policy's LUT activation, which is no kernel, is compiled the
+same way and must hold no gather.  Nothing runs, so no chip is needed;
+where the TPU compiler cannot describe the topology the tests skip.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import ops
 from repro.core import routing as R
 from repro.kernels import ops as kops
 from repro.ops import schedule_for
@@ -130,3 +133,19 @@ def test_kernel_compiles_to_mosaic_for_v5e(name, one_chip):
     fn, args = KERNELS[name](spec)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), name
+
+
+def test_default_lut_activation_compiles_without_gather_for_v5e(one_chip):
+    """The ``activation`` op under the default policy (impl ``lut``) at the
+    MoE wave's hidden buffer (4 groups × 8 slots × capacity × f): the TPU
+    program evaluates the table's entry in place, with no gather."""
+    x = jax.ShapeDtypeStruct((4, 8, CAPACITY, F), jnp.float32,
+                             sharding=one_chip)
+    ops.reset_dispatch_report()
+    with ops.use_policy(ops.ComputePolicy()):
+        compiled = jax.jit(
+            lambda v: ops.apply_activation(v, "gelu")).lower(x).compile()
+    assert ops.dispatch_report()["activation"]["hits"] == {"lut": 1}
+    hlo = compiled.as_text()
+    assert not re.search(r"\sgather\(", hlo)     # no gather instruction
+    assert "tpu_custom_call" not in hlo
