@@ -5,9 +5,10 @@ the cell's own size.  The benchmark's runs do not run this.
         --int8-seeds 1,2,3 --fp8-seeds 1,2,3 --fault-seeds 1,2,3
 
 For each seed, over every frame of the cell's pool and every task it
-sends (as many distinct answers as a run compares), the numbers of
-``harness.correct`` against the float32 reference, and the verdict that
-``harness.correct.compare`` gives them under the configuration's limits:
+sends (as many distinct answers as a run compares), the numbers of the
+vision entry (``harness.entries.vision``) against the float32 reference,
+and the verdict that its ``compare`` gives them under the
+configuration's limits:
 
   program  the served forward (``M3ViTServer.infer`` of the entry's
            backend, one bucket's batch at a time, the entry's defaults):
@@ -73,11 +74,11 @@ def main(argv=None) -> int:
     import run as bench_run
 
     bench_run.enable_cache()
-    from harness.correct import compare, numbers, reference_answers
     from harness.device import check_device
-    from harness.model import (arch_from, load_config, load_reference,
-                               make_frames, served_params)
-    from harness.traffic import load_mix
+    from harness.entries.vision import (arch_from, compare, load_mix,
+                                        make_frames, numbers,
+                                        reference_answers, served_params)
+    from harness.model import load_config, load_reference
     from harness.weights import make_weights
 
     check_device(1)

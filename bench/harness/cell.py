@@ -3,10 +3,14 @@ traffic for the window, read the metrics, and check the answers against
 the plain reference.
 
 Set-up (``setup_s``) is everything from the process's start to the
-window's start: imports, the device check, weights and frames made on the
+window's start: imports, the device check, weights and inputs made on the
 device, the entry built, and every shape the window uses run once.  The
 reference runs after the window, after the device's peak memory has been
 read and the served model has been let go; it is not counted anywhere.
+
+What is served, and how, is the configuration's entry
+(``harness/entries/<entry>.py``, named by the file's ``"entry"``);
+everything else about a run is here.
 """
 
 from __future__ import annotations
@@ -20,21 +24,15 @@ from dataclasses import dataclass
 from pathlib import Path
 from typing import Any, Callable, Optional
 
-import numpy as np
-
-from harness import correct as C
 from harness import flops as F
 from harness.device import check_device, memory_peak_bytes, peak_for
-from harness.drive import SLOW_STEP_S, run_window
-from harness.model import (arch_from, load_config, load_reference,
-                           make_frames, served_params)
-from harness.spans import CompileClock, Spans, instrument
-from harness.traffic import load_mix
-from harness.weights import make_weights
+from harness.drive import SLOW_STEP_S
+from harness.model import load_config, load_reference
+from harness.spans import CompileClock, Spans
 
-__all__ = ["Ctx", "run", "load_cell", "reader_path", "WARM_ROUNDS"]
+__all__ = ["Ctx", "run", "load_cell", "load_entry", "reader_path"]
 
-WARM_ROUNDS = 2          # full batches per task before the window
+ENTRIES = Path(__file__).resolve().parent / "entries"
 
 
 @dataclass
@@ -45,14 +43,23 @@ class Ctx:
     setup_s: float
     window: Any                        # harness.drive.Window
     completed_in_window: int
-    steps: int                         # bucket forwards in the window
+    steps: int                         # bucket forwards or decode steps
     slot_steps: int                    # requests they carried
     slots_per_bucket: int
-    cache: dict                        # expert-cache counters, window only
+    cache: dict                        # the entry's cache counters, window
     spans: Optional[Spans] = None
     trace: Any = None                  # harness.trace.Summary (traced run)
     peak: Any = None
     flops: Any = F
+
+
+def load_entry(cfg: dict):
+    """``harness/entries/<entry>.py`` of the configuration's ``entry``."""
+    name = cfg["entry"]
+    if not (ENTRIES / f"{name}.py").is_file():
+        raise ValueError(f"no served entry {name!r} (harness/entries/"
+                         f"{name}.py)")
+    return importlib.import_module(f"harness.entries.{name}")
 
 
 def load_cell(bench_root: Path, workload: str):
@@ -62,7 +69,8 @@ def load_cell(bench_root: Path, workload: str):
         raise SystemExit(f"bench: no workload {workload!r} in BENCHMARK.json")
     conf = next(c for c in spec["configs"] if c["name"] == wl["config"])
     cfg = load_config(bench_root.parent / conf["file"])
-    mix = load_mix(bench_root / "traffic" / f"{wl['traffic']}.json")
+    mix = load_entry(cfg).load_mix(
+        bench_root / "traffic" / f"{wl['traffic']}.json")
     e2e = [m for m in spec["end_to_end"]
            if workload in m.get("workloads", [workload])]
     # a per-layer metric without a list of cells is read in every cell
@@ -93,20 +101,6 @@ def _reader(bench_root: Path, name: str) -> Callable:
     return mod.read
 
 
-def _counters(sched) -> tuple[int, int]:
-    steps = slot_steps = 0
-    for b in sched.buckets.values():
-        steps += getattr(b, "steps", 0)
-        slot_steps += getattr(b, "slot_steps", 0)
-    return steps, slot_steps
-
-
-def _cache(sched) -> dict:
-    stats = getattr(sched.backend, "cache_stats", None)
-    s = stats() if callable(stats) else {}
-    return {k: s.get(k, 0) for k in ("hits", "misses", "bytes_paged")}
-
-
 def log(msg: str) -> None:
     print(f"[bench] {msg}", file=sys.stderr, flush=True)
 
@@ -115,12 +109,15 @@ def run(bench_root: Path, workload: str, seed: int, seconds: float,
         trace: bool, t_start: float, *, require_tpu: bool = True,
         cfg: Optional[dict] = None, mix=None,
         plant: Optional[Callable] = None,
+        judge: Optional[Callable] = None,
         trace_dir: Optional[Path] = None, also: tuple = ()) -> dict:
     """One run; returns the result line's object.  ``cfg`` and ``mix``
     replace the cell's files (tests run small sizes on the CPU; the knee
     sweep varies the rate); ``plant(sched)`` is called once the entry is
-    warm (tests break the timed path there); ``also`` names metrics read
-    besides the cell's own (the sweep's completed rate)."""
+    warm (tests break the timed path there); ``judge(served, answers)``
+    takes the place of ``served.verdict(answers)`` (a control judges the
+    window's answers another way); ``also`` names metrics read besides
+    the cell's own (the sweep's completed rate)."""
     import jax
 
     wl, file_cfg, file_mix, e2e, per_layer = load_cell(bench_root, workload)
@@ -130,40 +127,22 @@ def run(bench_root: Path, workload: str, seed: int, seconds: float,
     clock = CompileClock()
 
     from repro import ops
-    from repro.launch.serve import vision_scheduler
-    from repro.serve import Request
 
-    ref_mod = load_reference(bench_root, cfg)
-    arch = arch_from(cfg)
-    params = served_params(arch, ref_mod.param_spec(cfg), seed)
-    frames = make_frames(cfg, mix.frame_pool, seed)
-    sched = vision_scheduler(arch, params, batch=mix.slots)
-
-    def request(rid, task, image, arrival):
-        return Request(rid=rid, task_id=task, prompt=image, arrival=arrival)
-
-    # warm-up: full batches of every task the window sends, through the
-    # window's own scheduler and backend
-    per_bucket = sched.slots_per_bucket
-    for r in range(WARM_ROUNDS):
-        now = sched.now()
-        warm = [request(-1, t, frames[(r * per_bucket + j) % len(frames)],
-                        now)
-                for t in mix.tasks for j in range(per_bucket)]
-        sched.run(warm)
+    entry = load_entry(cfg)
+    served = entry.Served(cfg, mix, seed, load_reference(bench_root, cfg))
+    served.warm()
     log("dispatch report (which implementation served each op): "
         + json.dumps(ops.dispatch_report(), sort_keys=True, default=str))
     if plant is not None:
-        plant(sched)
+        plant(served.sched)
 
     spans = None
     step = None
     if trace:
         spans = Spans()
-        instrument(sched, spans)
-        step = spans.wrap(sched.step, "step")
-    steps0, slot0 = _counters(sched)
-    cache0 = _cache(sched)
+        served.instrument(spans)
+        step = spans.wrap(served.sched.step, "step")
+    count0 = served.counters()
     compile0 = clock.mark()
     tdir = None
     if trace:
@@ -176,14 +155,12 @@ def run(bench_root: Path, workload: str, seed: int, seconds: float,
     setup_s = time.perf_counter() - t_start
     if trace:
         with jax.profiler.TraceAnnotation("bench.window"):
-            win = run_window(sched, request, mix, seconds, seed, frames,
-                             step=step)
+            win = served.drive(seconds, seed, step)
         jax.profiler.stop_trace()
     else:
-        win = run_window(sched, request, mix, seconds, seed, frames)
+        win = served.drive(seconds, seed)
     compile1 = clock.mark()
-    steps1, slot1 = _counters(sched)
-    cache1 = _cache(sched)
+    count1 = served.counters()
     due = win.due()
     done_in = sum(1 for r in due
                   if r.t_done is not None and r.t_done <= win.end)
@@ -199,19 +176,19 @@ def run(bench_root: Path, workload: str, seed: int, seconds: float,
     device = dict(dev)
     device["memory_peak_bytes"] = memory_peak_bytes(jax.devices()[:wl["chips"]])
     ctx = Ctx(cfg=cfg, seconds=seconds, setup_s=setup_s, window=win,
-              completed_in_window=done_in, steps=steps1 - steps0,
-              slot_steps=slot1 - slot0, slots_per_bucket=per_bucket,
-              cache={k: cache1[k] - cache0[k] for k in cache1}, spans=spans,
+              completed_in_window=done_in,
+              steps=count1["steps"] - count0["steps"],
+              slot_steps=count1["slot_steps"] - count0["slot_steps"],
+              slots_per_bucket=served.sched.slots_per_bucket,
+              cache={k: v - count0["cache"][k]
+                     for k, v in count1["cache"].items()},
+              spans=spans,
               peak=peak_for(dev["kind"]) if require_tpu else None)
-    answers = [(f, r.task_id, None if r.t_done is None else
-                np.asarray(r.result))
-               for r, f in zip(win.requests, win.frames)
-               if win.start <= r.arrival < win.end]
-    failed = sum(1 for _, _, y in answers
-                 if y is None or not np.isfinite(y).all())
+    answers, failed = served.collect(win)
 
     # the served model goes before the reference comes
-    del sched, params, step
+    served.release()
+    del step
     gc.collect()
 
     breakdown = None
@@ -237,12 +214,9 @@ def run(bench_root: Path, workload: str, seed: int, seconds: float,
                          "unit": ""}
 
     t0 = time.perf_counter()
-    ref_w = make_weights(ref_mod.param_spec(cfg), seed)
-    reference = C.reference_answers(ref_mod, cfg, ref_w, frames,
-                                    [(f, t) for f, t, _ in answers])
-    verdict = C.compare(answers, reference, cfg["limits"],
-                        cfg["image"]["patch"])
-    log(f"reference over {len(reference)} frame-task pairs in "
+    verdict = (judge(served, answers) if judge is not None
+               else served.verdict(answers))
+    log(f"reference over {verdict['reference']} in "
         f"{time.perf_counter() - t0:.2f} s; {verdict['compared']} answers "
         f"compared; shown, not compared: {verdict['shown']!r}")
     for name, c in verdict["checks"].items():

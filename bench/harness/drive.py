@@ -2,9 +2,11 @@
 
 The loop is ``Scheduler.run``'s own (step; sleep half a millisecond when
 nothing is runnable), with two additions: the closed loop's clients send
-their next frame as each answer comes back, and the window closes at a
-fixed time.  Every request due inside the window is followed to its end,
-up to ``DRAIN_S`` past the close; one that is still open then has failed.
+their next request as each answer comes back, and the window closes at a
+fixed time.  What is sent comes from the entry's load (``Load``): the
+open mix's schedule, or each client's next input and task.  Every
+request due inside the window is followed to its end, up to ``DRAIN_S``
+past the close; one that is still open then has failed.
 Times are the scheduler's clock, seconds from its start.
 """
 
@@ -13,15 +15,37 @@ from __future__ import annotations
 import gc
 import time
 from dataclasses import dataclass, field
+from typing import Any, Protocol
 
 import numpy as np
 
-from harness.traffic import FrameOrder, Mix, open_schedule
-
-__all__ = ["Window", "run_window", "DRAIN_S", "SLOW_STEP_S"]
+__all__ = ["Load", "Window", "run_window", "step_counters", "DRAIN_S",
+           "SLOW_STEP_S"]
 
 DRAIN_S = 60.0
 SLOW_STEP_S = 0.4               # two forwards' time: a stall, not work
+
+
+class Load(Protocol):
+    """An entry's traffic for one window.  ``item`` is what the entry
+    needs to find a request's input again (a frame's index in the pool,
+    a prompt's), kept on the window beside each request."""
+
+    kind: str                       # "open" | "closed"
+    clients: int                    # closed: clients sending at once
+
+    def schedule(self, seconds: float) -> list:
+        """Open: [(due time in s from the window's start, item, task)] in
+        due order."""
+
+    def first(self, client: int) -> tuple[Any, int]:
+        """Closed: (item, task) of the client's first request."""
+
+    def then(self, client: int) -> tuple[Any, int]:
+        """Closed: (item, task) of the client's next request."""
+
+    def request(self, rid: int, item, task: int, arrival: float):
+        """The scheduler's request for ``item`` and ``task``."""
 
 
 @dataclass
@@ -30,7 +54,7 @@ class Window:
     end: float
     closed_at: float = 0.0          # when the last due request finished
     requests: list = field(default_factory=list)     # scheduler Requests
-    frames: list = field(default_factory=list)       # frame of each request
+    items: list = field(default_factory=list)        # item of each request
     steps: int = 0                                   # scheduler steps run
     longest_step: float = 0.0       # host seconds of the longest loop turn
     slow_steps: int = 0             # turns longer than SLOW_STEP_S
@@ -49,10 +73,18 @@ class Window:
                            for r in self.due()], np.float64)
 
 
-def run_window(sched, make_request, mix: Mix, seconds: float, seed: int,
-               images, step=None) -> Window:
-    """Offer ``mix`` to ``sched`` for ``seconds``.  ``make_request(rid,
-    task, image, arrival)`` builds the entry's request; ``step`` stands in
+def step_counters(sched) -> tuple[int, int]:
+    """(steps, slot steps) of the scheduler's buckets so far: bucket
+    forwards or decode steps, and the requests they carried."""
+    steps = slot_steps = 0
+    for b in sched.buckets.values():
+        steps += getattr(b, "steps", 0)
+        slot_steps += getattr(b, "slot_steps", 0)
+    return steps, slot_steps
+
+
+def run_window(sched, load: Load, seconds: float, step=None) -> Window:
+    """Offer ``load`` to ``sched`` for ``seconds``.  ``step`` stands in
     for ``sched.step`` (a traced run wraps it in a span)."""
     step = step or sched.step
     start = sched.now()
@@ -68,35 +100,31 @@ def run_window(sched, make_request, mix: Mix, seconds: float, seed: int,
 
     gc.callbacks.append(collected)
     try:
-        _drive(win, sched, make_request, mix, seconds, seed, images, step)
+        _drive(win, sched, load, seconds, step)
     finally:
         gc.callbacks.remove(collected)
     return win
 
 
-def _drive(win, sched, make_request, mix, seconds, seed, images, step):
+def _drive(win, sched, load, seconds, step):
     start = win.start
+    closed = load.kind == "closed"
 
-    def send(task: int, frame: int, due: float):
-        r = make_request(len(win.requests), task, images[frame], due)
+    def send(item, task: int, due: float):
+        r = load.request(len(win.requests), item, task, due)
         win.requests.append(r)
-        win.frames.append(frame)
+        win.items.append(item)
         sched.submit(r)
         return r
 
     client_of: dict[int, int] = {}
-    next_task: dict[int, int] = {}
-    rng = np.random.default_rng(seed)
-    if mix.kind == "open":
-        for due, frame, task in open_schedule(mix, seconds, seed):
-            send(task, frame, start + due)
-    else:
-        order = FrameOrder(mix.frame_pool, rng)
-        for c in range(mix.clients):
-            k = c % len(mix.tasks)
-            r = send(mix.tasks[k], order.next(), start)
+    if closed:
+        for c in range(load.clients):
+            r = send(*load.first(c), start)
             client_of[r.rid] = c
-            next_task[c] = (k + 1) % len(mix.tasks)
+    else:
+        for due, item, task in load.schedule(seconds):
+            send(item, task, start + due)
     seen = len(sched.finished)
     deadline = win.end + DRAIN_S
     turn = time.perf_counter()
@@ -106,14 +134,12 @@ def _drive(win, sched, make_request, mix, seconds, seed, images, step):
         done = sched.finished[seen:]
         seen = len(sched.finished)
         for r in done:
-            c = client_of.pop(r.rid, None) if mix.kind == "closed" else None
+            c = client_of.pop(r.rid, None) if closed else None
             if c is not None and r.t_done < win.end:
-                k = next_task[c]
-                nr = send(mix.tasks[k], order.next(), r.t_done)
+                nr = send(*load.then(c), r.t_done)
                 client_of[nr.rid] = c
-                next_task[c] = (k + 1) % len(mix.tasks)
         now = sched.now()
-        if not sched.pending() and (mix.kind == "open" or now >= win.end):
+        if not sched.pending() and (not closed or now >= win.end):
             break
         if now > deadline:
             break

@@ -4,9 +4,10 @@ layer of the served entry, and the clock of JAX's compilations.
 ``Spans`` wraps a callable so that each call is a
 ``jax.profiler.TraceAnnotation`` named ``bench.<name>`` (what labels the
 device's idle gaps in a trace) and, where asked, records its host time.
-A traced run instruments; an untimed run never does.  An attribute that
-the program does not have is skipped: a span then goes missing, and its
-time falls to the span around it.
+A traced run instruments (each entry's ``instrument`` says where); an
+untimed run never does.  An attribute that the program does not have is
+skipped: a span then goes missing, and its time falls to the span around
+it.
 """
 
 from __future__ import annotations
@@ -16,7 +17,7 @@ from collections import defaultdict
 
 import jax
 
-__all__ = ["Spans", "CompileClock", "instrument"]
+__all__ = ["Spans", "CompileClock"]
 
 
 class Spans:
@@ -47,45 +48,6 @@ class Spans:
             for k, fn in list(mapping.items()):
                 if callable(fn):
                     mapping[k] = self.wrap(fn, name)
-
-
-class _Layer:
-    """A paged MoE layer seen through a span: calls go through one,
-    everything else reaches the layer itself."""
-
-    def __init__(self, layer, call):
-        self._layer = layer
-        self._call = call
-
-    def __call__(self, *args, **kwargs):
-        return self._call(*args, **kwargs)
-
-    def __getattr__(self, name):
-        return getattr(self._layer, name)
-
-
-def instrument(sched, spans: Spans) -> None:
-    """Spans around the served entry's layers: the scheduler's step is
-    wrapped by the caller; here the vision backend's forward (timed), its
-    stages, each paged MoE layer, the expert cache's ``ensure`` and slot
-    writes, and the task heads."""
-    server = getattr(sched.backend, "server", None)
-    if server is None:
-        return
-    spans.wrap_attr(server, "infer", "infer", timed=True)
-    spans.wrap_attr(server, "prefetch", "prefetch")
-    for attr, name in (("_embed", "embed"), ("_dense", "dense"),
-                       ("_moe_pre", "moe_pre"), ("_final", "final_norm")):
-        spans.wrap_attr(server, attr, name)
-    spans.wrap_items(getattr(server, "_heads", None), "head")
-    paged = getattr(server, "paged", None)
-    if isinstance(paged, dict):
-        for i, layer in list(paged.items()):
-            cache = getattr(layer, "cache", None)
-            if cache is not None:
-                spans.wrap_attr(cache, "ensure", "ensure")
-                spans.wrap_attr(cache, "_write", "cache_write")
-            paged[i] = _Layer(layer, spans.wrap(layer, "moe"))
 
 
 class CompileClock:

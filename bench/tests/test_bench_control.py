@@ -10,9 +10,9 @@ from pathlib import Path
 import pytest
 
 import control as CT
-from harness.correct import compare, reference_answers
-from harness.model import (arch_from, load_config, load_reference,
-                           make_frames, served_params)
+from harness.entries.vision import (arch_from, compare, make_frames,
+                                    reference_answers, served_params)
+from harness.model import load_config, load_reference
 from harness.weights import make_weights
 
 BENCH = Path(__file__).resolve().parents[1]

@@ -5,7 +5,7 @@ moved, a lost or a non-finite answer fail."""
 import numpy as np
 import pytest
 
-from harness.correct import FAR, compare, numbers
+from harness.entries.vision import FAR, compare, numbers
 
 PATCH = 2              # 128 tokens an answer
 LIMITS = {"worst_token_error": 0.04, "worst_task_bias": 0.01,

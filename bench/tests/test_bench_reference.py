@@ -11,8 +11,9 @@ import numpy as np
 import pytest
 
 from harness.correct import rel_l2
-from harness.model import (arch_from, load_config, load_reference,
-                           make_frames, served_params, served_spec)
+from harness.entries.vision import (arch_from, make_frames, served_params,
+                                    served_spec)
+from harness.model import load_config, load_reference
 from harness.weights import make_weights, seed_words
 
 BENCH = Path(__file__).resolve().parents[1]

@@ -13,8 +13,8 @@ import pytest
 
 from harness import faults
 from harness.cell import reader_path, run
+from harness.entries.vision import load_mix
 from harness.model import load_config
-from harness.traffic import load_mix
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent

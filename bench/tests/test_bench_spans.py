@@ -13,7 +13,8 @@ import pytest
 from harness import program_spans as PS
 from harness import trace as T
 from harness.cell import _reader
-from harness.spans import Spans, _Layer, instrument
+from harness.entries.vision import _Layer, instrument
+from harness.spans import Spans
 from test_bench_run import small_run
 from test_bench_trace import FIXTURE, synthetic
 

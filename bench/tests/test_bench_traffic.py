@@ -6,7 +6,8 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from harness.traffic import FrameOrder, load_mix, open_schedule
+from harness.entries.vision import load_mix, open_schedule
+from harness.traffic import PoolOrder
 
 TRAFFIC = Path(__file__).resolve().parents[1] / "traffic"
 
@@ -50,10 +51,10 @@ def test_burst_frames_share_a_due_time():
 
 
 def test_frame_order_visits_the_pool_before_repeating():
-    order = FrameOrder(8, np.random.default_rng(4))
+    order = PoolOrder(8, np.random.default_rng(4))
     first = [order.next() for _ in range(8)]
     assert sorted(first) == list(range(8))
-    again = FrameOrder(8, np.random.default_rng(4))
+    again = PoolOrder(8, np.random.default_rng(4))
     assert [again.next() for _ in range(8)] == first
 
 
